@@ -45,6 +45,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 if __package__ in (None, ""):  # `python benchmarks/serving_mesh.py`
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
@@ -53,7 +54,6 @@ from benchmarks.serving_batch import build_program
 from benchmarks.serving_groups import SUBSETS
 from repro.core import MSP430
 from repro.launch.hlo_cost import analyze_hlo
-from repro.launch.mesh import make_mesh
 from repro.serving import (
     EnginePolicy, MultitaskEngine, MultitaskRequest, RequestGroupScheduler,
 )
@@ -126,7 +126,9 @@ def main(argv=None) -> int:
 
     prog = build_program(dim)
     reqs = trace_requests(n_req, dim)
-    mesh = make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh(
+        (4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
     configs = {
         "single": None,
         "tp": TP_POLICY,

@@ -1,5 +1,9 @@
 """mistral-nemo-12b — Mistral-Nemo-Base-2407, 128k context
-[hf:mistralai/Mistral-Nemo-Base-2407].
+[hf:mistralai/Mistral-Nemo-Base-2407, config.json].
+
+Widths follow the published ``config.json``: 40 layers, hidden 5120, 32
+query / 8 KV heads of ``head_dim`` 128 (so the query width, 4096, is not the
+hidden size), intermediate 14336, vocab 131072, rope theta 1e6.
 
 Full attention natively; ``long_context_window`` enables the beyond-paper
 sliding-window variant used only for the long_500k decode shape (DESIGN §5).
@@ -9,10 +13,10 @@ from repro.models.config import make_config
 CONFIG = make_config(
     name="mistral-nemo-12b", family="dense",
     num_layers=40, d_model=5120, n_heads=32, n_kv_heads=8,  # GQA kv=8
-    d_ff=14336, vocab_size=131072, head_dim=160,
+    d_ff=14336, vocab_size=131072, head_dim=128,
     activation="swiglu", rope_theta=1e6,
     long_context_window=4096,
-    citation="hf:mistralai/Mistral-Nemo-Base-2407",
+    citation="hf:mistralai/Mistral-Nemo-Base-2407 config.json",
 )
 
 SMOKE = make_config(

@@ -1,6 +1,6 @@
-"""Pallas TPU kernels (validated interpret=True on CPU) + jnp oracles.
+"""Pallas TPU kernels + jnp oracles.
 
-The affinity kernel resolves its backend automatically (see
+Every kernel resolves its backend automatically (see
 :func:`repro.kernels.pearson_affinity.resolve_interpret`): Mosaic on TPU,
 interpreter elsewhere, explicit override for tests.
 """

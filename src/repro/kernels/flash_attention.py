@@ -7,9 +7,9 @@ is finalised on the last KV step.  Block shapes keep the working set in
 VMEM: ``q_blk x d`` + ``kv_blk x d`` tiles plus an ``q_blk x kv_blk`` score
 tile, all multiples of 128 on the matmul dims for MXU alignment.
 
-This container is CPU-only: the kernel is validated with
-``interpret=True`` against :func:`repro.kernels.ref.flash_attention_ref`
-(and the model-side oracle ``repro.models.layers.attention_chunked``).
+The kernel is validated in interpret mode against
+:func:`repro.kernels.ref.flash_attention_ref` (and the model-side oracle
+``repro.models.layers.attention_chunked``).
 """
 from __future__ import annotations
 
@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.pearson_affinity import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -86,13 +88,16 @@ def flash_attention(
     window: Optional[int] = None,
     q_blk: int = 128,
     kv_blk: int = 128,
-    interpret: bool = True,       # CPU container: interpret by default
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Pallas flash attention over flattened (batch*heads) slices.
 
     Sequence lengths are padded to the block sizes; padding keys are masked
     by the in-kernel ``k_pos < kv_len`` guard and padded queries sliced off.
+    ``interpret=None`` resolves from the backend (Mosaic on TPU, interpreter
+    elsewhere); pass an explicit bool to override.
     """
+    interpret = resolve_interpret(interpret)
     bh, s, d = q.shape
     t = k.shape[1]
     sm_scale = 1.0 / math.sqrt(d)

@@ -2,10 +2,9 @@
 
 Each op normalises layouts (e.g. (B,S,H,D) -> flattened (B*H,S,D) slices for
 attention), handles GQA head grouping, picks block sizes, and exposes an
-``interpret`` flag.  For the affinity kernel ``interpret`` defaults to
-``None`` and is resolved from the active backend (Mosaic on TPU, interpreter
-on CPU/GPU); the attention/scan kernels still default to the interpreter
-pending the same treatment on a real TPU target.
+``interpret`` flag.  ``interpret`` defaults to ``None`` and is resolved from
+the active backend (Mosaic on TPU, interpreter on CPU/GPU); an explicit bool
+overrides.
 """
 from __future__ import annotations
 
@@ -31,7 +30,7 @@ def flash_attention_bhsd(
     window: Optional[int] = None,
     q_blk: int = 128,
     kv_blk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """GQA flash attention in model layout: repeats KV heads to match Q."""
     b, s, hq, d = q.shape
@@ -71,6 +70,6 @@ def ssd_scan(
     x: jax.Array, dt: jax.Array, a: jax.Array,
     b_in: jax.Array, c_in: jax.Array,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     return _ssd(x, dt, a, b_in, c_in, chunk=chunk, interpret=interpret)

@@ -1,79 +1,94 @@
 """Pallas TPU kernel for the Mamba2 chunked SSD scan (arXiv:2405.21060).
 
-One grid step processes one (batch, chunk) tile for ALL heads: the
-intra-chunk quadratic term (masked-decay attention over the chunk) and the
-inter-chunk state recurrence, with the running (H, P, N) state carried in
-VMEM scratch across the sequential chunk axis.  Grid ``(B, S/Q)`` with the
-chunk axis innermost; the state scratch is re-zeroed at chunk 0 of every
-batch row.
+One grid step processes one (batch, head, chunk) tile: the intra-chunk
+quadratic term (masked-decay attention over the chunk) and the inter-chunk
+state recurrence, with the head's running state carried in VMEM scratch
+across the sequential chunk axis.  Grid ``(B, H, S/Q)`` with the chunk axis
+innermost; the state scratch is re-zeroed at chunk 0 of every (batch, head).
 
-Per-tile working set (fp32): Q*H*P (x) + Q*N (B,C) + H*P*N (state) + Q*Q*H
-(decay tile) — sized to sit comfortably in 128 MB-class VMEM for
-(Q=128, H<=96/16 per model shard, P=64, N=128).
+Every in-kernel operation is a 2-D elementwise op, a row broadcast or a 2-D
+matmul, which is what Mosaic lowers.  The wrapper does the layout work in
+XLA: it forms ``dt * x`` and ``dt * a`` and hands the kernel head-major
+tiles.  Prefix sums over the chunk are triangular-ones matmuls, since Mosaic
+has no cumsum.  On TPU the chunk must be a multiple of 128: it is the lane
+dimension of the ``dt * a`` and ``B^T`` tiles.
+
+Per-tile working set (fp32): Q*P (dt*x) + 2*Q*N (B, C) + N*P (state) + a few
+Q*Q decay tiles — well inside VMEM for (Q=128, P=64, N=128).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.pearson_affinity import resolve_interpret
+
+_HI = jax.lax.Precision.HIGHEST
+
 
 def _ssd_kernel(
-    x_ref,      # (1, Q, H, P)
-    dt_ref,     # (1, Q, H)
-    a_ref,      # (H,)
-    b_ref,      # (1, Q, N)
-    c_ref,      # (1, Q, N)
-    y_ref,      # (1, Q, H, P)
-    fin_ref,    # (1, H, P, N) final state output (written on last chunk)
-    state_scr,  # VMEM (H, P, N) running inter-chunk state
+    dx_ref,     # (Q, P)  dt * x of this head
+    da_ref,     # (1, Q)  dt * a of this head
+    bt_ref,     # (N, Q)  B transposed
+    c_ref,      # (Q, N)
+    y_ref,      # (Q, P)
+    fin_ref,    # (N, P)  final state (transposed), written on the last chunk
+    state_scr,  # VMEM (N, P) running inter-chunk state (transposed)
     *,
     q: int,
     nc: int,
 ):
-    ci = pl.program_id(1)
+    ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _reset():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0].astype(jnp.float32)          # (Q, H, P)
-    dt = dt_ref[0].astype(jnp.float32)        # (Q, H)
-    a = a_ref[...].astype(jnp.float32)        # (H,)
-    bb = b_ref[0].astype(jnp.float32)         # (Q, N)
-    cc = c_ref[0].astype(jnp.float32)         # (Q, N)
+    dx = dx_ref[...].astype(jnp.float32)
+    da = da_ref[...].astype(jnp.float32)
+    bt = bt_ref[...].astype(jnp.float32)
+    cc = c_ref[...].astype(jnp.float32)
+    p = dx.shape[1]
 
-    da = dt * a[None, :]                      # (Q, H)
-    da_cum = jnp.cumsum(da, axis=0)           # inclusive
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    causal = row >= col
+    # Inclusive prefix sums cum_i = sum_{k<=i} da_k, as matmuls:
+    #   (causal * da) @ ones  -> cum_i along row i (any width);
+    #   broadcast(da) @ causal^T -> cum_j along column j.
+    tri_da = jnp.where(causal, da, 0.0)
+    cum_i = jnp.dot(tri_da, jnp.ones((q, q), jnp.float32), precision=_HI)
+    cum_ip = jnp.dot(tri_da, jnp.ones((q, p), jnp.float32), precision=_HI)
+    cum_j = jnp.dot(
+        jnp.broadcast_to(da, (q, q)), (row <= col).astype(jnp.float32),
+        precision=_HI,
+    )
+    cum_last = cum_ip[q - 1:q, :]                                  # (1, P)
 
     # Intra-chunk: y_i = sum_{j<=i} (C_i.B_j) exp(cum_i - cum_j) dt_j x_j
-    cb = cc @ bb.T                            # (Q, Q)
-    decay = jnp.exp(da_cum[:, None, :] - da_cum[None, :, :])      # (Q, Q, H)
-    causal = (
-        jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    cb = jnp.dot(cc, bt)                                           # (Q, Q)
+    lmat = jnp.where(causal, jnp.exp(cum_i - cum_j), 0.0) * cb
+    y = jnp.dot(lmat, dx)                                          # (Q, P)
+
+    # Inter-chunk: y_i += exp(cum_i) * C_i . state_prev
+    state = state_scr[...]                                         # (N, P)
+    y += jnp.exp(cum_ip) * jnp.dot(cc, state)
+
+    # State update: state = state * exp(cum_Q) + sum_j B_j dt_j x_j exp(cum_Q - cum_j)
+    state_scr[...] = state * jnp.exp(cum_last) + jnp.dot(
+        bt, dx * jnp.exp(cum_last - cum_ip)
     )
-    lmat = jnp.where(causal[:, :, None], decay, 0.0) * cb[:, :, None]  # (Q,Q,H)
-    dx = dt[:, :, None] * x                    # (Q, H, P)
-    y = jnp.einsum("ijh,jhp->ihp", lmat, dx)
 
-    # Inter-chunk: y_i += C_i . state_prev * exp(cum_i)
-    state = state_scr[...]                     # (H, P, N)
-    y += jnp.einsum("in,hpn,ih->ihp", cc, state, jnp.exp(da_cum))
-
-    # State update: state = state * exp(cum_Q) + sum_j B_j x dx_j exp(cum_Q - cum_j)
-    to_end = jnp.exp(da_cum[-1][None, :] - da_cum)  # (Q, H)
-    s_chunk = jnp.einsum("jn,jh,jhp->hpn", bb, to_end, dx)
-    state_scr[...] = state * jnp.exp(da_cum[-1])[:, None, None] + s_chunk
-
-    y_ref[0] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _final():
-        fin_ref[0] = state_scr[...].astype(fin_ref.dtype)
+        fin_ref[...] = state_scr[...].astype(fin_ref.dtype)
 
 
 def ssd_scan(
@@ -83,9 +98,14 @@ def ssd_scan(
     b_in: jax.Array,   # (B, S, N)
     c_in: jax.Array,   # (B, S, N)
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
-    """Chunked SSD.  Returns (y (B,S,H,P), final_state (B,H,P,N) fp32)."""
+    """Chunked SSD.  Returns (y (B,S,H,P), final_state (B,H,P,N) fp32).
+
+    ``interpret=None`` resolves from the backend (Mosaic on TPU, interpreter
+    elsewhere); pass an explicit bool to override.
+    """
+    interpret = resolve_interpret(interpret)
     bsz, s, h, p = x.shape
     n = b_in.shape[-1]
     if s % chunk != 0:
@@ -96,26 +116,28 @@ def ssd_scan(
         c_in = jnp.pad(c_in, ((0, 0), (0, pad), (0, 0)))
     s_pad = x.shape[1]
     nc = s_pad // chunk
-    grid = (bsz, nc)
+    dt32 = dt.astype(jnp.float32)
+    dx = (x.astype(jnp.float32) * dt32[..., None]).transpose(0, 2, 1, 3)
+    da = (dt32 * a.astype(jnp.float32)).transpose(0, 2, 1)[:, :, None, :]
+    bt = b_in.transpose(0, 2, 1)
     y, fin = pl.pallas_call(
         functools.partial(_ssd_kernel, q=chunk, nc=nc),
-        grid=grid,
+        grid=(bsz, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, h, p), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, chunk, h), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((h,), lambda b, c: (0,)),
-            pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((None, None, chunk, p), lambda b, hh, c: (b, hh, c, 0)),
+            pl.BlockSpec((None, None, 1, chunk), lambda b, hh, c: (b, hh, 0, c)),
+            pl.BlockSpec((None, n, chunk), lambda b, hh, c: (b, 0, c)),
+            pl.BlockSpec((None, chunk, n), lambda b, hh, c: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, h, p), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, h, p, n), lambda b, c: (b, 0, 0, 0)),
+            pl.BlockSpec((None, None, chunk, p), lambda b, hh, c: (b, hh, c, 0)),
+            pl.BlockSpec((None, None, n, p), lambda b, hh, c: (b, hh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s_pad, h, p), x.dtype),
-            jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, h, s_pad, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, n, p), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((h, p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a, b_in, c_in)
-    return y[:, :s], fin
+    )(dx, da, bt, c_in)
+    return y.transpose(0, 2, 1, 3)[:, :s], fin.transpose(0, 1, 3, 2)

@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.configs import get_config, list_archs
 from repro.launch.hlo_cost import analyze_hlo
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (
     RooflineReport, active_params, model_flops_estimate,
 )
@@ -66,7 +66,7 @@ def run_one(
     # NTP adjustment mid-compile.
     t0 = time.perf_counter()
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             plan = make_plan(cfg, shape, mesh, policy)
             # Decode updates its cache in place (§Perf C3): donating the
             # cache argument lets XLA alias the output buffer.
@@ -156,22 +156,14 @@ def run_one(
 
 
 def _mem_dict(mem) -> dict:
-    out = {}
-    for attr in (
-        "argument_size_in_bytes", "output_size_in_bytes",
-        "temp_size_in_bytes", "alias_size_in_bytes",
-        "generated_code_size_in_bytes",
-    ):
-        try:
-            out[attr] = float(getattr(mem, attr))
-        except (AttributeError, TypeError):
-            # Only the expected shape mismatches across jaxlib versions: a
-            # missing accessor or a non-numeric return.  Anything else
-            # (e.g. a RuntimeError from a dead backend) should surface.
-            pass
-    if not out and mem is not None:
-        out["repr"] = str(mem)[:2000]
-    return out
+    return {
+        attr: float(getattr(mem, attr))
+        for attr in (
+            "argument_size_in_bytes", "output_size_in_bytes",
+            "temp_size_in_bytes", "alias_size_in_bytes",
+            "generated_code_size_in_bytes",
+        )
+    }
 
 
 def _write(result: dict, out_dir: Optional[str], arch: str, shape: str, mesh: str):

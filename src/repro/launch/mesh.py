@@ -4,44 +4,23 @@ A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state.  The single-pod mesh is 16x16 = 256 chips
 (data, model); the multi-pod mesh is 2x16x16 = 512 chips (pod, data, model)
 where "pod" is an additional data-parallel axis whose collectives cross the
-inter-pod (DCN-class) links.
+inter-pod (DCN-class) links.  Axes are ``Auto``: the model code places
+tensors through sharding constraints, not through sharding-in-types.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer jax;
-    older releases build the same Auto-typed mesh without the argument.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on newer jax; on older releases ``Mesh`` itself is the
-    context manager that installs the legacy global mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke usage (axes present but size 1)."""
-    return make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

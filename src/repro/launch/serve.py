@@ -13,13 +13,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config, list_archs
-from repro.launch.mesh import make_host_mesh, make_production_mesh, set_mesh
+from repro.launch.compile_cache import configure_compile_cache
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import get_model
 from repro.serving import LMServer
 from repro.sharding.policy import TP_POLICY
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="granite-34b")
     ap.add_argument("--smoke", action="store_true")
@@ -33,7 +35,7 @@ def main() -> None:
     mesh = make_production_mesh() if args.production_mesh else make_host_mesh()
     model = get_model(cfg)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         srv = LMServer(model, params, TP_POLICY)
         rng = np.random.default_rng(0)

@@ -28,7 +28,7 @@ from repro.sharding.policy import (
     EXPERT_TP_POLICY, FSDP_EXPERT_POLICY, FSDP_TP_POLICY, ShardingPolicy,
     TP_POLICY,
 )
-from repro.sharding.utils import fit_specs, to_named_shardings, tree_bytes
+from repro.sharding.utils import fit_specs, tree_bytes
 from repro.training.optimizer import AdamWConfig, AdamWState
 from repro.training.train_loop import make_train_step
 
@@ -124,18 +124,6 @@ class LoweringPlan:
     kind: str
 
 
-def _compat_shardings(tree: Any, mesh: Mesh) -> Any:
-    """Spec trees jit will accept on this jax version.
-
-    Newer jax (with ``jax.set_mesh``) takes bare ``PartitionSpec``s against
-    the ambient mesh; older releases require concrete ``NamedSharding``s
-    (``None`` leaves stay ``None`` — unspecified is accepted everywhere).
-    """
-    if hasattr(jax, "set_mesh"):
-        return tree
-    return to_named_shardings(tree, mesh)
-
-
 def _batch_spec(cfg: ModelConfig, shape: InputShape, policy: ShardingPolicy, mesh: Mesh):
     b = policy.physical("batch")
     if cfg.family == "encdec" and shape.kind in ("train", "prefill"):
@@ -169,8 +157,8 @@ def make_plan(
         out_shardings = (pspec, ospec, None)  # metrics replicated
         return LoweringPlan(
             cfg, shape, policy, step, (psds, osds, bsds),
-            _compat_shardings((pspec, ospec, bspec), mesh),
-            _compat_shardings(out_shardings, mesh), "train",
+            (pspec, ospec, bspec),
+            out_shardings, "train",
         )
 
     if shape.kind == "prefill":
@@ -187,8 +175,8 @@ def make_plan(
         out_shardings = (None, cache_spec)
         return LoweringPlan(
             cfg, shape, policy, prefill_step, (psds, bsds),
-            _compat_shardings((pspec, bspec), mesh),
-            _compat_shardings(out_shardings, mesh), "prefill",
+            (pspec, bspec),
+            out_shardings, "prefill",
         )
 
     # decode
@@ -205,6 +193,6 @@ def make_plan(
     return LoweringPlan(
         cfg, shape, policy, serve_step,
         (psds, spec_in["token"], csds, spec_in["cache_len"]),
-        _compat_shardings((pspec, tok_spec, cspec, P()), mesh),
-        _compat_shardings(out_shardings, mesh), "decode",
+        (pspec, tok_spec, cspec, P()),
+        out_shardings, "decode",
     )

@@ -20,7 +20,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, get_smoke_config, list_archs
 from repro.data import lm_batches
-from repro.launch.mesh import make_host_mesh, make_production_mesh, set_mesh
+from repro.launch.compile_cache import configure_compile_cache
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import get_model
 from repro.sharding.policy import TP_POLICY
 from repro.sharding.utils import fit_specs
@@ -30,6 +31,7 @@ from repro.training import (
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="granite-34b")
     ap.add_argument("--smoke", action="store_true",
@@ -50,7 +52,7 @@ def main() -> None:
     policy = TP_POLICY
     model = get_model(cfg)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         pspec = fit_specs(params, model.param_specs(policy), mesh)
         params = jax.tree.map(

@@ -125,20 +125,23 @@ def build_transformer_program(
 ) -> MultitaskProgram:
     """Blocks = contiguous transformer layer ranges; heads = linear probes.
 
-    The depth-0 block also owns the embedding table (it is always the
-    root-most shared computation).  Task "heads" classify the mean-pooled
-    final hidden state — the multitask-serving analogue of the paper's
-    per-task dense classifier.
+    The depth-0 block also owns the token embedding table (it is always the
+    root-most shared computation); no LM head is built, since no task
+    decodes.  Task "heads" classify the last position's final hidden state
+    — the multitask-serving analogue of the paper's per-task dense
+    classifier.
     """
     from repro.models import transformer as T
 
     ranges = _split_layers(cfg.num_layers, graph.depth)
-    q_pos = jnp.arange(seq_len, dtype=jnp.int32)
 
     def make_block_fn(depth: int):
         a, b = ranges[depth]
 
         def apply(p: Params, x: jax.Array) -> jax.Array:
+            # Built per trace, so the program can also be traced from
+            # parameter shapes alone (jax.eval_shape of this function).
+            q_pos = jnp.arange(seq_len, dtype=jnp.int32)
             if depth == 0:
                 x = L.embed_tokens(p["embed"], x, cfg, policy)
 
@@ -158,7 +161,10 @@ def build_transformer_program(
         layers = jax.vmap(lambda k: T._init_layer(k, cfg))(keys)
         p: Params = {"layers": layers}
         if depth == 0:
-            p["embed"] = L.init_embed(jax.random.fold_in(key, 7), cfg)
+            p["embed"] = {"embedding": L.embed_init(
+                jax.random.fold_in(key, 7), cfg.vocab_size, cfg.d_model,
+                cfg.params_dtype(),
+            )}
         return p
 
     node_params: Dict[NodeId, Params] = {}

@@ -28,27 +28,13 @@ Logical = Union[None, str, Tuple[str, ...]]
 
 
 def _ambient_mesh():
-    """The mesh installed by ``with mesh:`` / ``jax.sharding.use_mesh``.
+    """The mesh installed by ``jax.set_mesh``, or None outside one.
 
-    Only ``ImportError`` / ``AttributeError`` — the "this jax version does
-    not have that accessor" signals — mean "try the next accessor"; anything
-    else is a real failure in mesh state and must surface, not silently
-    degrade every spec to replicated.
+    A failing accessor raises: swallowing it would silently degrade every
+    spec to replicated.
     """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        if m is not None and not m.empty:
-            return m
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        m = None
-    if m is not None and not m.empty:
-        return m
-    return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 @dataclasses.dataclass(frozen=True)

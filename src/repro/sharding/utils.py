@@ -13,7 +13,7 @@ from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _axis_size(mesh: Mesh, axis: Union[str, Tuple[str, ...]]) -> int:
@@ -58,14 +58,6 @@ def fit_specs(shapes: Any, specs: Any, mesh: Mesh) -> Any:
 
     return jax.tree.map(
         one, shapes, specs, is_leaf=lambda x: isinstance(x, P)
-    )
-
-
-def to_named_shardings(specs: Any, mesh: Mesh) -> Any:
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s),
-        specs,
-        is_leaf=lambda x: isinstance(x, P),
     )
 
 

@@ -374,9 +374,11 @@ def test_adaptive_composes_with_restored_checkpoint():
 @pytest.mark.skipif(jax.device_count() < 8,
                     reason="needs 8 (forced host) devices")
 def test_adaptive_composes_with_mesh():
-    from repro.launch.mesh import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
     rng = np.random.default_rng(10)
     reqs = [MultitaskRequest(x=x, tasks=s)
             for x, s in zip(_inputs(rng, 4), [None, (0, 1), (2, 3, 4), None])]

@@ -22,9 +22,9 @@ from repro.launch.specs import make_plan
 from repro.launch.hlo_cost import analyze_hlo
 from repro.models.config import InputShape
 
-from repro.launch.mesh import make_mesh, set_mesh
+from jax.sharding import AxisType
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 out = {}
 cases = [
     ("granite-34b", InputShape("t", 64, 8, "train")),
@@ -33,7 +33,7 @@ cases = [
     ("zamba2-2.7b", InputShape("d", 64, 8, "decode")),
     ("whisper-medium", InputShape("t", 64, 8, "train")),
 ]
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for arch, shape in cases:
         cfg = get_smoke_config(arch)
         plan = make_plan(cfg, shape, mesh, "tp")
@@ -54,6 +54,8 @@ print(json.dumps(out))
 def test_make_plan_lowers_on_8_device_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # The child must never reach for an accelerator the parent may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     res = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,

@@ -18,11 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import BlockCost, MSP430, MultitaskProgram
 from repro.core.task_graph import TaskGraph
 from repro.launch.hlo_cost import analyze_hlo
-from repro.launch.mesh import make_mesh
 from repro.serving import (
     EnginePolicy, MultitaskEngine, MultitaskRequest, RequestGroupScheduler,
 )
@@ -81,7 +81,9 @@ def _requests(rng, subsets):
 
 def _mesh_engine(sharding):
     return MultitaskEngine(PROGRAM, hw=MSP430, policy=EnginePolicy(
-        mesh=make_mesh((4, 2), ("data", "model")),
+        mesh=jax.make_mesh(
+            (4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+        ),
         sharding=sharding,
         scheduler=RequestGroupScheduler(batch_shapes=(1, 4)),
     ))

@@ -243,11 +243,13 @@ needs_mesh = pytest.mark.skipif(
 
 
 def _mesh_engine(**kwargs):
-    from repro.launch.mesh import make_mesh
+    from jax.sharding import AxisType
     from repro.sharding.policy import TP_POLICY
 
     return MultitaskEngine(PROGRAM, hw=MSP430, policy=EnginePolicy(
-        mesh=make_mesh((4, 2), ("data", "model")),
+        mesh=jax.make_mesh(
+            (4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+        ),
         sharding=TP_POLICY,
         scheduler=RequestGroupScheduler(batch_shapes=(1, 4)),
     ), **kwargs)

@@ -91,9 +91,8 @@ def test_data_and_weight_shard_counts():
 
 def test_ambient_mesh_propagates_accessor_failures(monkeypatch):
     """Regression: _ambient_mesh used to swallow *every* exception, so a
-    broken mesh context silently degraded all specs to replicated.  Only
-    version-absence signals (ImportError/AttributeError on the private
-    fallback) may be swallowed; a failing public accessor must surface."""
+    broken mesh context silently degraded all specs to replicated.  A
+    failing accessor must surface."""
     def boom():
         raise RuntimeError("mesh state corrupted")
 
@@ -105,6 +104,21 @@ def test_ambient_mesh_propagates_accessor_failures(monkeypatch):
 
 
 def test_ambient_mesh_none_without_context():
+    assert _ambient_mesh() is None
+
+
+def test_ambient_mesh_under_set_mesh_drives_specs():
+    from jax.sharding import AxisType
+
+    mesh = jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
+    with jax.set_mesh(mesh):
+        ambient = _ambient_mesh()
+        assert ambient is not None
+        assert ambient.axis_names == ("data", "model")
+        # "pod" is absent from this mesh and drops out of the batch axes.
+        assert TP_POLICY.spec("batch", "model") == P(("data",), "model")
     assert _ambient_mesh() is None
 
 
