@@ -62,6 +62,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.constraints import Constraints
+from repro.core.spans import span
 from repro.core.task_graph import TaskGraph
 from repro.core.types import (
     BlockCost, ExecutionStats, NodeId, TaskGateRecord,
@@ -109,6 +110,19 @@ def _leaf_specs(params: Any) -> Tuple:
     """(treedef, leaf shapes/dtypes) fingerprint for stackability checks."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
     return treedef, tuple((jnp.shape(l), jnp.result_type(l)) for l in leaves)
+
+
+def _named(fn: Callable, name: str, batched: bool,
+           shape: Tuple[int, ...]) -> Callable:
+    """``fn`` renamed ``<name>_b<rows>`` (``<name>`` unbatched): ``jax.jit``
+    names its XLA module ``jit_<name>...``, so the device trace says which
+    depths and batch shape ran.  The task is left out on purpose: tasks at
+    one resume depth and batch shape run the same HLO, and the module name
+    is part of the persistent compilation cache's key, so naming the task
+    would compile and store that program once per task."""
+    fn.__name__ = fn.__qualname__ = (
+        f"{name}_b{shape[0]}" if batched else name)
+    return fn
 
 
 def _gate_bcast(fire: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
@@ -348,6 +362,11 @@ class TaskGraphExecutor:
         # not part of ExecutionStats (those are cost-model-predictable logical
         # counters — dispatches depend on the fused/per-block mode).
         self.dispatch_count = 0
+        # Batch rows of every batched task run (one dispatch on the fused
+        # path), and how many of them were padding: physical counts, like
+        # ``dispatch_count``, and not part of ExecutionStats.
+        self.rows_dispatched = 0
+        self.rows_padded = 0
         # Adaptive-gating readback: per-dispatch realized fire masks of the
         # current task (``(start_depth, bool array)`` fragments, one per
         # dispatched segment), the finished task's TaskGateRecord, and the
@@ -771,7 +790,10 @@ class TaskGraphExecutor:
                         stacked_fired,
                     )
 
-        compiled = jax.jit(fused) if self._jit else fused
+        compiled = (
+            jax.jit(_named(fused, f"suffix_r{resume}", batched, shape))
+            if self._jit else fused
+        )
         self._compiled_fused[key] = (compiled, mode)
         return compiled, mode
 
@@ -988,7 +1010,10 @@ class TaskGraphExecutor:
                     )
                     return tuple(acts), stacked_fired
 
-        compiled = jax.jit(seg) if self._jit else seg
+        compiled = (
+            jax.jit(_named(seg, f"segment_{start}_{stop}", batched, shape))
+            if self._jit else seg
+        )
         self._compiled_segment[key] = (compiled, mode)
         return compiled, mode
 
@@ -1308,6 +1333,8 @@ class TaskGraphExecutor:
         checkpoint_depths: Sequence[int] = (),
         checkpoint_hook: Optional[Callable[[int], None]] = None,
         row_mask: Optional[Any] = None,
+        valid: Optional[int] = None,
+        group_id: Optional[int] = None,
     ) -> jnp.ndarray:
         """Run one task for a stacked request group ``xs``: ``(B, *sample)``.
 
@@ -1332,14 +1359,24 @@ class TaskGraphExecutor:
         ``row_mask`` (optional ``(B,)`` bool) marks which rows are logically
         live for adaptive fire accounting — exactly ``weight`` of them; see
         :meth:`_run_task_impl`.
+
+        ``valid`` (default ``B``) is how many leading rows are requests, the
+        rest being padding: it feeds :attr:`rows_padded`.  ``group_id``, the
+        serving session's group id, labels the ``repro.dispatch`` span.
         """
-        w = int(xs.shape[0]) if weight is None else int(weight)
-        return self._run_task_impl(
-            task, xs, stats, w, batched=True,
-            checkpoint_depths=checkpoint_depths,
-            checkpoint_hook=checkpoint_hook,
-            row_mask=row_mask,
-        )
+        rows = int(xs.shape[0])
+        w = rows if weight is None else int(weight)
+        with span("dispatch", group=group_id, task=task, rows=rows) as run:
+            out = self._run_task_impl(
+                task, xs, stats, w, batched=True,
+                checkpoint_depths=checkpoint_depths,
+                checkpoint_hook=checkpoint_hook,
+                row_mask=row_mask,
+            )
+            run.set_metadata(resume=self.last_gate_record.resume)
+        self.rows_dispatched += rows
+        self.rows_padded += rows - (rows if valid is None else int(valid))
+        return out
 
     def run_batch(
         self,
@@ -1379,7 +1416,8 @@ class TaskGraphExecutor:
                 stats.tasks_skipped += v
                 self.last_trace.append(TaskGateRecord(task=t, weight=0))
                 continue
-            results[t] = self.run_task_batch(t, xs, stats, weight=v)
+            results[t] = self.run_task_batch(
+                t, xs, stats, weight=v, valid=v)
             self.last_trace.append(self.last_gate_record)
         return results, stats
 

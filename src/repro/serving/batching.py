@@ -41,6 +41,7 @@ import numpy as np
 from repro.core.constraints import Constraints
 from repro.core.cost_model import GraphCostModel, Residency
 from repro.core.ordering import greedy_2opt_order, optimal_order
+from repro.core.spans import span
 from repro.models.registry import ModelApi
 from repro.sharding.policy import ShardingPolicy, TP_POLICY
 
@@ -217,9 +218,10 @@ class RequestGroupScheduler:
                     valid=take,
                 ))
         if cost_model is not None and task_order is not None:
-            groups = order_groups(
-                groups, cost_model, task_order, initial_resident
-            )
+            with span("order", groups=len(groups)):
+                groups = order_groups(
+                    groups, cost_model, task_order, initial_resident
+                )
         return groups
 
 

@@ -31,6 +31,7 @@ from repro.core.constraints import Constraints
 from repro.core.cost_model import CheckpointSite, GraphCostModel
 from repro.core.executor import MultitaskProgram, TaskGraphExecutor
 from repro.core.ordering import optimal_order, solve_suborder
+from repro.core.spans import span
 from repro.core.types import (
     ExecutionStats, HardwareModel, TPU_V5E, TaskGateRecord,
 )
@@ -501,7 +502,8 @@ class MultitaskEngine:
             # the *expected* cost matrix (see _resolve_matrix), so the
             # per-plan orders optimize the same probability-weighted
             # objective (Eq. 8) as the global solve.
-            groups = self._resolve_plan_orders(groups)
+            with span("order", groups=len(groups)):
+                groups = self._resolve_plan_orders(groups)
         return groups
 
     def group_order(self, group: RequestGroup) -> Tuple[int, ...]:
@@ -658,6 +660,7 @@ class MultitaskEngine:
         executor: Optional[TaskGraphExecutor] = None,
         intermittent: Optional[IntermittentContext] = None,
         ckpt_plan: Optional[Sequence["CheckpointSite"]] = None,
+        group_id: Optional[int] = None,
     ) -> Tuple[List[Dict[int, jax.Array]], ExecutionStats,
                List[TaskGateRecord]]:
         """Execute one homogeneous request group through the batched path.
@@ -685,6 +688,9 @@ class MultitaskEngine:
 GateModelCalibrator` consumes and what
         ``GraphCostModel.predicted_stats(..., gate_trace=...)`` replays to
         reproduce ``stats`` field-exactly.
+
+        ``group_id`` (the serving session's group id) labels the executor's
+        dispatch spans.
         """
         ex = executor if executor is not None else self.executor
         v = group.valid
@@ -724,10 +730,12 @@ GateModelCalibrator` consumes and what
                     t, group.xs, stats, weight=fired,
                     checkpoint_depths=[s.depth for s in sites],
                     checkpoint_hook=hook, row_mask=row_mask,
+                    valid=v, group_id=group_id,
                 )
             else:
                 out = ex.run_task_batch(
-                    t, group.xs, stats, weight=fired, row_mask=row_mask
+                    t, group.xs, stats, weight=fired, row_mask=row_mask,
+                    valid=v, group_id=group_id,
                 )
             if ex.last_gate_record is not None:
                 trace.append(dataclasses.replace(
@@ -825,6 +833,7 @@ GateModelCalibrator` consumes and what
         first_task_resume: int = 0,
         keep_activations: bool = False,
         adaptive_threshold: Optional[float] = None,
+        group_id: Optional[int] = None,
     ) -> GroupExecution:
         """Run one planned group; the session's execution primitive.
 
@@ -857,6 +866,8 @@ GateModelCalibrator` consumes and what
         restored activation checkpoint at depth ``d`` enters with
         ``first_task_resume=d+1`` and must *not* clear the activation cache
         at the boundary — the restored checkpoint is the whole point.
+
+        ``group_id`` is the session's id of the group, for its trace spans.
         """
         self._inject("plan", group_tasks=group.tasks, valid=group.valid)
         if keep_activations:
@@ -903,62 +914,70 @@ GateModelCalibrator` consumes and what
         pending_stall = streamer.pending_stall_seconds
         self._inject("load", group_tasks=group.tasks, resume=resume)
         per_request, stats, trace = self._run_group(
-            group, eff, intermittent=intermittent, ckpt_plan=ckpt_plan
+            group, eff, intermittent=intermittent, ckpt_plan=ckpt_plan,
+            group_id=group_id,
         )
-        stats.stream_stall_seconds += streamer.finish_group()
-        # Realized-conditional prediction: replay the gate trace over the
-        # *pre-execution* residency.  All-fire traces reproduce the
-        # historical pre-execution prediction bit for bit; gated/adaptive
-        # traces keep ``stats == predicted`` field-exact.
-        predicted = self.cost_model.predicted_stats(
-            eff, batch_size=group.valid, resume=resume,
-            collectives=self.executor.collective_view(group.xs),
-            first_task_resume=first_task_resume,
-            checkpoints=ckpt_plan,
-            gate_trace=trace,
-        )
-        warm_saved = 0.0
-        if self.warm_start:
-            # Collectives are resume-independent (they key on the intra-order
-            # shared prefix), and warm_saved only reads the load counter —
-            # the cold reference needs no collective terms.  It DOES need
-            # ``first_task_resume``: the trace's resume depths come from the
-            # executed walk, and a crash-recovered group resumed mid-suffix
-            # — a cold-from-0 walk would reject its trace as divergent.
-            cold_pred = self.cost_model.predicted_stats(
-                eff, batch_size=group.valid, gate_trace=trace,
+        with span("predict", group=group_id):
+            stats.stream_stall_seconds += streamer.finish_group()
+            # Realized-conditional prediction: replay the gate trace over
+            # the *pre-execution* residency.  All-fire traces reproduce the
+            # historical pre-execution prediction bit for bit;
+            # gated/adaptive traces keep ``stats == predicted`` field-exact.
+            predicted = self.cost_model.predicted_stats(
+                eff, batch_size=group.valid, resume=resume,
+                collectives=self.executor.collective_view(group.xs),
                 first_task_resume=first_task_resume,
+                checkpoints=ckpt_plan,
+                gate_trace=trace,
             )
-            warm_saved = (
-                cold_pred.weight_bytes_loaded - predicted.weight_bytes_loaded
-            )
-        if staged:
-            # A prefetched group: the loads that hit staged copies arrived
-            # over the stream, so predict them as prefetched plus the
-            # staged batch's modelled stall.  For an ungated engine the
-            # staged set *is* the load set (prefetch_group planned it from
-            # the same residency), making this exact by construction; a
-            # legacy gate that skipped a whole task drops its staged-but-
-            # unused loads from both sides via the trace.
-            pf_bytes = sum(
-                self.program.block_costs[d].weight_bytes
-                for d, node in self.cost_model.plan_loads(
-                    eff, resume, gate_trace=trace
+            warm_saved = 0.0
+            if self.warm_start:
+                # Collectives are resume-independent (they key on the
+                # intra-order shared prefix), and warm_saved only reads the
+                # load counter — the cold reference needs no collective
+                # terms.  It DOES need ``first_task_resume``: the trace's
+                # resume depths come from the executed walk, and a
+                # crash-recovered group resumed mid-suffix — a cold-from-0
+                # walk would reject its trace as divergent.
+                cold_pred = self.cost_model.predicted_stats(
+                    eff, batch_size=group.valid, gate_trace=trace,
+                    first_task_resume=first_task_resume,
                 )
-                if node in staged
+                warm_saved = (
+                    cold_pred.weight_bytes_loaded
+                    - predicted.weight_bytes_loaded
+                )
+            if staged:
+                # A prefetched group: the loads that hit staged copies
+                # arrived over the stream, so predict them as prefetched
+                # plus the staged batch's modelled stall.  For an ungated
+                # engine the staged set *is* the load set (prefetch_group
+                # planned it from the same residency), making this exact by
+                # construction; a legacy gate that skipped a whole task
+                # drops its staged-but-unused loads from both sides via the
+                # trace.
+                pf_bytes = sum(
+                    self.program.block_costs[d].weight_bytes
+                    for d, node in self.cost_model.plan_loads(
+                        eff, resume, gate_trace=trace
+                    )
+                    if node in staged
+                )
+                if pf_bytes > 0.0:
+                    predicted.prefetched_bytes = pf_bytes
+                    predicted.stream_stall_seconds = pending_stall
+            predicted.tasks_skipped += (
+                (len(self.order) - len(eff)) * group.valid
             )
-            if pf_bytes > 0.0:
-                predicted.prefetched_bytes = pf_bytes
-                predicted.stream_stall_seconds = pending_stall
-        predicted.tasks_skipped += (len(self.order) - len(eff)) * group.valid
-        if self._calibrator is not None:
-            # Online calibration: fold this group's realized trace into the
-            # gate model so expected-cost planning tracks traffic drift.
-            self._calibrator.observe(trace)
-            self.cost_model = dataclasses.replace(
-                self.cost_model, gate_model=self._calibrator.model()
-            )
-            self._resolve_mat = None
+            if self._calibrator is not None:
+                # Online calibration: fold this group's realized trace into
+                # the gate model so expected-cost planning tracks traffic
+                # drift.
+                self._calibrator.observe(trace)
+                self.cost_model = dataclasses.replace(
+                    self.cost_model, gate_model=self._calibrator.model()
+                )
+                self._resolve_mat = None
         return GroupExecution(
             group=group, eff=eff, outputs=per_request, stats=stats,
             predicted=predicted, warm_saved=warm_saved,

@@ -104,13 +104,13 @@ alone is a complete (if fully synchronous) usage.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import time
 from typing import (
-    TYPE_CHECKING, Callable, Deque, Dict, Iterable, List, Optional, Tuple,
+    TYPE_CHECKING, Callable, Dict, Iterable, List, Optional, Tuple,
 )
 
+from repro.core.spans import span
 from repro.core.types import ExecutionStats
 from repro.serving.reliability import (
     DeadlineExceeded, QueueFull, RequestError, RetryPolicy, TenantStats,
@@ -420,19 +420,13 @@ class ServingSession:
         # by ``ServingSession.recover``; empty for ordinary sessions).
         self.recovered: Dict[int, MultitaskFuture] = {}
         # Admission-latency tracking: running aggregates over every admitted
-        # request (exact for the session's whole lifetime) plus a bounded
-        # window of recent samples — a long-lived session must not grow a
-        # per-request list forever.  ``tenants`` keeps the same exact
-        # aggregates per tenant label (None = untenanted), so quota/SLO
+        # request (exact for the session's whole lifetime; a long-lived
+        # session keeps no per-request list).  ``tenants`` keeps the same
+        # exact aggregates per tenant label (None = untenanted), so quota/SLO
         # policies can observe per-tenant starvation the global mean hides.
-        self.waits: Deque[float] = collections.deque(maxlen=self.WAITS_WINDOW)
         self.wait_sum = 0.0
         self.wait_max = 0.0
         self.tenants: Dict[Optional[str], TenantStats] = {}
-
-    #: recent admission-latency samples kept in ``waits`` (aggregates in
-    #: ``wait_sum`` / ``wait_max`` / ``mean_admission_wait`` cover all).
-    WAITS_WINDOW = 4096
 
     @property
     def mean_admission_wait(self) -> float:
@@ -571,12 +565,14 @@ class ServingSession:
         """One scheduling pump: admit/plan/execute whatever the policy says
         is ready at ``now``.  Returns the responses resolved by this pump
         (execution order, possibly including groups dispatched earlier)."""
-        return self._pump(self._now(now), flush=False)
+        with span("pump", flush=False):
+            return self._pump(self._now(now), flush=False)
 
     def flush(self, now: Optional[float] = None) -> List["MultitaskResponse"]:
         """Pump with flush semantics: thresholds off, queue emptied, and the
         last in-flight group resolved."""
-        return self._pump(self._now(now), flush=True)
+        with span("pump", flush=True):
+            return self._pump(self._now(now), flush=True)
 
     def drain(self) -> List["MultitaskResponse"]:
         """Serve until nothing is pending.
@@ -628,7 +624,6 @@ class ServingSession:
 
     def _record_wait(self, entry: PendingRequest, now: float) -> None:
         wait = now - entry.arrival
-        self.waits.append(wait)
         self.wait_sum += wait
         self.wait_max = max(self.wait_max, wait)
         tstats = self.tenant_stats(entry.tenant)
@@ -640,22 +635,27 @@ class ServingSession:
         completed: List["MultitaskResponse"] = []
         self._expire_deadlines(now)
         while True:
-            admitted = self.policy.admit(self.queue, self.engine, now, flush)
+            with span("admit") as admit:
+                admitted = self.policy.admit(
+                    self.queue, self.engine, now, flush)
+                for p in admitted:
+                    self._record_wait(p, now)
+                admit.set_metadata(admitted=len(admitted))
             if not admitted:
                 break
             self.admission_rounds += 1
             self.requests_admitted += len(admitted)
-            for p in admitted:
-                self._record_wait(p, now)
             try:
                 # Planning (bucketing, group-ordering TSP, per-plan
                 # re-solve) is host-only work; any previously dispatched
                 # group is still executing asynchronously on the device
-                # underneath it.
-                t0 = time.perf_counter()
-                groups = self.engine.plan_groups(
-                    [p.request for p in admitted])
-                self.plan_seconds += time.perf_counter() - t0
+                # underneath it.  ``plan_seconds`` times the span's interval.
+                with span("plan", requests=len(admitted)) as plan:
+                    t0 = time.perf_counter()
+                    groups = self.engine.plan_groups(
+                        [p.request for p in admitted])
+                    self.plan_seconds += time.perf_counter() - t0
+                    plan.set_metadata(groups=len(groups))
             except Exception as err:
                 # Planning failed before any group existed: group
                 # membership is unknown, so the whole admitted batch fails
@@ -689,51 +689,70 @@ class ServingSession:
                     # still executing asynchronously on the device; stream
                     # this group's non-resident weights behind them.
                     self._prefetch(group)
-                execution, retries, degraded = self._run_group_guarded(
-                    group, members, group_id,
-                    adaptive_threshold=self._ladder_threshold(members, now))
-                if execution is None:
-                    # Ladder exhausted; members already failed.  No window
-                    # survives a failed group — the next prefetch would
-                    # overlap with compute that never completed.
-                    self._stream_budget = 0.0
-                    continue
-                self.groups_executed += 1
-                if self.streaming:
-                    self._stream_budget = execution.predicted.compute_seconds(
-                        self.engine.hw
-                    )
-                if self.energy is not None:
-                    # Spend what the group actually cost (gated groups can
-                    # undershoot the all-gates-fire reservation; clamp keeps
-                    # rounding at the reservation boundary benign).
-                    spent = execution.stats.energy(self.engine.hw)
-                    self.energy.drain(min(spent, self.energy.available))
-                self.stats = self.stats.merge(execution.stats)
-                self.predicted = self.predicted.merge(execution.predicted)
-                self.expected = self.expected.merge(
-                    execution.expected if execution.expected is not None
-                    else execution.predicted
-                )
-                if self.journal is not None:
-                    # Atomic commit: outputs + counters + the residency the
-                    # group leaves behind, in one durable record.  Futures
-                    # resolve only after this point, so a delivered response
-                    # is always a journaled response — exactly-once.
-                    self.journal.group_commit(
-                        group_id, [p.seq for p in members],
-                        execution.outputs,
-                        self.engine.executor.residency_state(),
-                        execution.stats,
-                    )
-                # Resolve immediately: building responses is non-blocking
-                # host work (outputs are unsynced JAX arrays, the modelled
-                # seconds come from counters), so deferring resolution
-                # would buy no extra overlap — and a failure in a later
-                # group must not strand futures whose group already ran.
-                completed.extend(self._resolve(
-                    execution, members, retries=retries, degraded=degraded))
+                with span("group", group=group_id, valid=group.valid,
+                          rows=int(group.xs.shape[0])) as run:
+                    execution, retries, degraded = self._run_group_guarded(
+                        group, members, group_id,
+                        adaptive_threshold=self._ladder_threshold(
+                            members, now))
+                    run.set_metadata(attempt=retries + 1)
+                    if execution is None:
+                        # Ladder exhausted; members already failed.  No
+                        # window survives a failed group — the next prefetch
+                        # would overlap with compute that never completed.
+                        self._stream_budget = 0.0
+                        continue
+                    # Resolve immediately: building responses is
+                    # non-blocking host work (outputs are unsynced JAX
+                    # arrays, the modelled seconds come from counters), so
+                    # deferring resolution would buy no extra overlap — and
+                    # a failure in a later group must not strand futures
+                    # whose group already ran.
+                    completed.extend(self._commit_group(
+                        execution, members, group_id, retries, degraded))
         return completed
+
+    def _commit_group(
+        self,
+        execution: "GroupExecution",
+        members: Tuple[PendingRequest, ...],
+        group_id: int,
+        retries: int,
+        degraded: Optional[str],
+    ) -> List["MultitaskResponse"]:
+        """Merge one executed group into the session's counters, journal
+        its commit, and resolve its members' futures."""
+        with span("resolve", group=group_id, requests=len(members)):
+            self.groups_executed += 1
+            if self.streaming:
+                self._stream_budget = execution.predicted.compute_seconds(
+                    self.engine.hw
+                )
+            if self.energy is not None:
+                # Spend what the group actually cost (gated groups can
+                # undershoot the all-gates-fire reservation; clamp keeps
+                # rounding at the reservation boundary benign).
+                spent = execution.stats.energy(self.engine.hw)
+                self.energy.drain(min(spent, self.energy.available))
+            self.stats = self.stats.merge(execution.stats)
+            self.predicted = self.predicted.merge(execution.predicted)
+            self.expected = self.expected.merge(
+                execution.expected if execution.expected is not None
+                else execution.predicted
+            )
+            if self.journal is not None:
+                # Atomic commit: outputs + counters + the residency the
+                # group leaves behind, in one durable record.  Futures
+                # resolve only after this point, so a delivered response
+                # is always a journaled response — exactly-once.
+                self.journal.group_commit(
+                    group_id, [p.seq for p in members],
+                    execution.outputs,
+                    self.engine.executor.residency_state(),
+                    execution.stats,
+                )
+            return self._resolve(
+                execution, members, retries=retries, degraded=degraded)
 
     # --------------------------------------------------- energy budgeting
     def _group_required_joules(self, group) -> float:
@@ -947,7 +966,7 @@ class ServingSession:
         try:
             return self.engine._execute_group(
                 group, intermittent=intermittent,
-                adaptive_threshold=adaptive_threshold,
+                adaptive_threshold=adaptive_threshold, group_id=group_id,
             )
         except BaseException:
             self.engine.executor.set_residency(snapshot)

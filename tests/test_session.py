@@ -292,9 +292,10 @@ def test_window_policy_admits_by_size_and_age():
     assert session.step() == [] and not f4.done()
     clock.advance(1.5)
     assert len(session.step()) == 1 and f4.done()
-    # Admission latency was recorded.
-    assert len(session.waits) == 4
-    assert session.waits[-1] == pytest.approx(1.5)
+    # Admission latency was recorded: three admitted at once, one at 1.5.
+    assert session.requests_admitted == 4
+    assert session.wait_sum == pytest.approx(1.5)
+    assert session.wait_max == pytest.approx(1.5)
 
 
 def test_window_policy_respects_group_size_cap():
