@@ -136,20 +136,17 @@ def build_transformer_program(
     ranges = _split_layers(cfg.num_layers, graph.depth)
 
     def make_block_fn(depth: int):
-        a, b = ranges[depth]
-
         def apply(p: Params, x: jax.Array) -> jax.Array:
             # Built per trace, so the program can also be traced from
             # parameter shapes alone (jax.eval_shape of this function).
             q_pos = jnp.arange(seq_len, dtype=jnp.int32)
             if depth == 0:
                 x = L.embed_tokens(p["embed"], x, cfg, policy)
-
-            def body(h, lp):
-                h2, _, _ = T._layer_apply(lp, h, cfg, policy, q_pos)
-                return h2, None
-
-            x, _ = jax.lax.scan(body, x, p["layers"])
+            # Straight-line layers over separate buffers: a loop over
+            # stacked weights makes XLA copy each layer's slice out of the
+            # stack before its matmuls.
+            for lp in p["layers"]:
+                x, _, _ = T._layer_apply(lp, x, cfg, policy, q_pos)
             return x
 
         return apply
@@ -158,7 +155,12 @@ def build_transformer_program(
         a, b = ranges[depth]
         n = b - a
         keys = jax.random.split(key, n)
-        layers = jax.vmap(lambda k: T._init_layer(k, cfg))(keys)
+        stacked = jax.vmap(lambda k: T._init_layer(k, cfg))(keys)
+        # One parameter dict per layer, each leaf its own buffer; the draw
+        # is the stacked one, so the values do not depend on the layout.
+        layers = tuple(
+            jax.tree.map(lambda w, i=i: w[i], stacked) for i in range(n)
+        )
         p: Params = {"layers": layers}
         if depth == 0:
             p["embed"] = {"embedding": L.embed_init(

@@ -147,3 +147,83 @@ def test_encdec_decode_matches_forward():
     np.testing.assert_allclose(
         np.asarray(step), np.asarray(full[:, 19]), atol=3e-3, rtol=3e-3
     )
+
+
+# --------------------------------------------------------------------------
+# Task-tree blocks (models/multitask.py): per-layer weight buffers
+# --------------------------------------------------------------------------
+
+TREE = ([[0, 1]], [[0], [1]])  # two depths of 3 layers each
+TREE_SEQ = 16
+
+
+def _tree_program(jit):
+    from repro.core.task_graph import TaskGraph
+    from repro.models.multitask import build_transformer_program
+
+    cfg = _dense_cfg(num_layers=6)
+    graph = TaskGraph.from_groups(TREE)
+    built = {}
+
+    def init(key):
+        built["prog"] = build_transformer_program(
+            key, graph, cfg, (3, 5), seq_len=TREE_SEQ
+        )
+        return built["prog"].node_params
+
+    params = (jax.jit(init) if jit else init)(jax.random.PRNGKey(0))
+    return cfg, graph, built["prog"], params
+
+
+def _stacked_draws(cfg, graph, jit):
+    """Each node's layers drawn as ``build_transformer_program`` draws them:
+    one stacked ``vmap`` draw of the node's 3 layers."""
+    def draw(key):
+        out = {}
+        for node in graph.nodes():
+            key, sub = jax.random.split(key)
+            out[node] = jax.vmap(lambda k: T._init_layer(k, cfg))(
+                jax.random.split(sub, 3))
+        return out
+
+    return (jax.jit(draw) if jit else draw)(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("jit", [False, True])
+def test_task_tree_layers_are_separate_buffers_of_the_stacked_draw(jit):
+    cfg, graph, _prog, params = _tree_program(jit)
+    draws = _stacked_draws(cfg, graph, jit)
+    one = jax.eval_shape(lambda k: T._init_layer(k, cfg), jax.random.PRNGKey(0))
+    for node in graph.nodes():
+        layers = params[node]["layers"]
+        assert isinstance(layers, tuple) and len(layers) == 3
+        for i, lp in enumerate(layers):
+            assert jax.tree.map(jnp.shape, lp) == jax.tree.map(lambda s: s.shape, one)
+            want = jax.tree.map(lambda w: w[i], draws[node])
+            for a, b in zip(jax.tree.leaves(lp), jax.tree.leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_task_tree_block_matches_a_scan_over_stacked_layers():
+    from repro.models import layers as L
+
+    cfg, graph, prog, params = _tree_program(jit=False)
+    q_pos = jnp.arange(TREE_SEQ, dtype=jnp.int32)
+
+    def scanned(p, x, depth):
+        if depth == 0:
+            x = L.embed_tokens(p["embed"], x, cfg, P)
+        stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *p["layers"])
+
+        def body(h, lp):
+            return T._layer_apply(lp, h, cfg, P, q_pos)[0], None
+
+        return jax.lax.scan(body, x, stacked)[0]
+
+    x = jax.random.randint(jax.random.PRNGKey(1), (2, TREE_SEQ), 0, 300)
+    for d, node in enumerate(graph.path(0)):
+        got = jax.jit(prog.block_fns[d])(params[node], x)
+        want = jax.jit(scanned, static_argnums=2)(params[node], x, d)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        x = got

@@ -7,8 +7,10 @@ module-scoped fixture (never while a module is imported: only one process
 may load the TPU library, and every xdist worker imports every test file).
 All such tests live in this one file so that one worker owns the library.
 """
+import collections
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.pearson_affinity import pearson_dissimilarity
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models.multitask import build_transformer_program
+from repro.models.transformer import _init_layer
 from repro.sharding.policy import TP_POLICY
 from repro.sharding.utils import fit_spec
 
@@ -141,6 +144,92 @@ def test_sharded_fused_suffix_compiles_on_four_chips(topo):
     # Each chip holds about a quarter of the path's weights.
     assert weights / 4 <= mem.argument_size_in_bytes < weights / 3
     assert "all-reduce" in compiled.as_text()
+
+
+# One HLO instruction: its name, result type, opcode and the rest of the line.
+_INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%?(\S+)\s*=\s*(.+?)\s([a-z][\w-]*)\((.*)$")
+
+
+def _computations(hlo):
+    """The instruction lines of each computation of an HLO text, by name
+    (``ENTRY`` for the entry computation)."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith((" ", "HloModule")):
+            name = "ENTRY" if line.startswith("ENTRY") else line.split()[0].lstrip("%")
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _weight_writes(hlo, weight_shapes):
+    """Every top-level instruction of ENTRY and of each loop body that
+    writes a buffer shaped like a layer weight, with or without a leading
+    layer axis of 1: ``(opcode, dims, op_name)`` of each copy, slice,
+    dynamic-slice and loop fusion."""
+    comps = _computations(hlo)
+    found = []
+    for name in ["ENTRY", *re.findall(r"body=%?([\w.\-]+)", hlo)]:
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if m is None:
+                continue
+            _, result, op, rest = m.groups()
+            if op not in ("copy", "slice", "dynamic-slice") and not (
+                op == "fusion" and "kind=kLoop" in rest
+            ):
+                continue
+            source = re.search(r'op_name="([^"]*)"', rest)
+            for dims in re.findall(r"\w+\[([\d,]*)\]", result):
+                shape = tuple(int(d) for d in dims.split(",") if d)
+                if shape in weight_shapes or (
+                    shape[:1] == (1,) and shape[1:] in weight_shapes
+                ):
+                    found.append((op, shape, source and source.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("resume, rows", [(2, 1), (0, 16)])
+def test_fused_suffix_reads_layer_weights_in_place(one_chip, resume, rows):
+    """The smoke's tree has 2 layers a depth, as the benchmark's t4
+    configuration: its fused suffix runs the layers straight, reading each
+    weight from its own buffer, with no loop over stacked layers and no
+    slice of a weight written to memory before the matmuls that read it."""
+    prog = _smoke_program_shapes()
+    ex = TaskGraphExecutor(prog)
+    path = prog.graph.path(TASK)
+    x = jax.ShapeDtypeStruct((rows, 1, chip_smoke.SEQ_LEN), jnp.int32)
+    for d in range(resume):
+        x = jax.eval_shape(jax.vmap(prog.block_fns[d], in_axes=(None, 0)),
+                           prog.node_params[path[d]], x)
+    fn, _mode = ex._fused_fn(TASK, resume, True, x.shape, x.dtype)
+    params = tuple(
+        prog.node_params[path[d]] for d in range(resume, prog.graph.depth)
+    )
+    hlo = fn.lower(
+        _placed(params, one_chip),
+        _placed(prog.head_params[TASK], one_chip),
+        _sds(x.shape, x.dtype, one_chip),
+    ).compile().as_text()
+    assert not re.search(r"\swhile\(", hlo)
+    layer = jax.eval_shape(lambda k: _init_layer(k, chip_smoke.full_width_config()),
+                           jax.random.PRNGKey(0))
+    shapes = {tuple(w.shape) for w in jax.tree.leaves(layer) if w.ndim >= 2}
+    writes = _weight_writes(hlo, shapes)
+    # A slice of a weight, or a copy carrying a layer axis, is what a loop
+    # over stacked layers costs.
+    assert [w for w in writes if w[0] != "copy" or w[1][0] == 1] == []
+    if rows == 1:
+        assert writes == []
+    else:
+        # At 16 rows the compiler lays some matmul operands out afresh, on
+        # either layout of the tree: at most once per argument buffer.
+        sources = collections.Counter(w[2] for w in writes)
+        assert all(s and s.startswith("params_tuple") for s in sources)
+        assert max(sources.values(), default=0) <= 1
 
 
 def test_flash_attention_compiles(one_chip):
