@@ -8,13 +8,19 @@ own, found by its name:
 
 * the configuration file that ``BENCHMARK.json`` names (``configs/<name>.json``):
   backbone sizes as published, the cut, the task tree and the serving policy;
+* ``archs/<model_type>.py``  -- the configuration's architecture: the
+  program's configuration object, a tree node's plain reference (weights and
+  forward pass) and its required FLOPs;
 * ``traffic/<traffic>.json`` -- parameters of the one general traffic generator;
-* ``metrics/<metric>.py``    -- a reader with ``read(window) -> float | None``;
+* ``metrics/<metric>.py``    -- a reader with ``read(window) -> float | None``,
+  and the program counters it reads (``COUNTERS``);
 * ``checks/<cell>.json``     -- the limits of the cell's output comparison.
 
 The yardstick lives here too: the traffic generator (``traffic.py``), the
-required-FLOPs function and the table of peaks (``flops.py``), the plain
-float32 reference of the configuration (``reference.py``), the comparison that
-decides ``correct`` (``compare.py``) and the reduction of a profiler trace to
-device busy time, top operations and idle gaps (``trace.py``).
+request's required FLOPs over tree nodes and the table of peaks
+(``flops.py``), the plain float32 reference of the task tree
+(``reference.py``), the comparison that decides ``correct`` (``compare.py``),
+the reduction of a profiler trace to device busy time, per-operation device
+time and idle gaps (``trace.py``) and of the program's spans in it
+(``program_spans.py``).
 """

@@ -2,16 +2,17 @@
 
 A request asks for a subset of the tree's tasks.  What it requires is the
 union of the tree nodes on the paths of those tasks, each node once: the
-shared prefix runs once however many tasks hang below it.  Each node is a
-range of decoder layers at the configuration's widths; a layer requires its
-projection and feed-forward matmuls for every token and causal attention
-(half of the score matrix and half of its product with V).  Padded rows,
-recomputed prefixes, attention over padded keys, the embedding lookup and the
-task heads (a few kFLOP) are not counted, so a share of the peak built on
-this can only understate what the chip executed.
+shared prefix runs once however many tasks hang below it.  What one node
+requires over one prompt is its architecture's ``node_flops(cfg, depth)``
+(``archs/<model_type>.py``), which counts the matmuls each token needs and
+causal attention.  Padded rows, recomputed prefixes, attention over padded
+keys, the embedding lookup and the task heads (a few kFLOP) are not counted,
+so a share of the peak built on this can only understate what the chip
+executed.
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, FrozenSet, Iterable, List, Sequence, Tuple
 
 # Peaks of one chip, keyed by ``jax.Device.device_kind``.  A kind that is not
@@ -54,16 +55,5 @@ def request_nodes(tree, tasks: Iterable[int]) -> FrozenSet[Node]:
     return frozenset(n for t in tasks for n in path(tree, t))
 
 
-def layer_flops(cfg: Dict) -> float:
-    """One decoder layer over one prompt of ``seq_len`` tokens."""
-    s, d, f = cfg["seq_len"], cfg["hidden_size"], cfg["intermediate_size"]
-    h, hk, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    matmul_params = d * h * hd + 2 * d * hk * hd + h * hd * d + 3 * d * f
-    causal_attention = 2.0 * s * s * h * hd  # QK^T and PV, half the scores each
-    return 2.0 * s * matmul_params + causal_attention
-
-
-def request_flops(cfg: Dict, tasks: Iterable[int]) -> float:
-    per_layer = layer_flops(cfg)
-    layers = cfg["layers_per_depth"]
-    return sum(layers[d] * per_layer for d, _g in request_nodes(cfg["tree"], tasks))
+def request_flops(arch: ModuleType, cfg: Dict, tasks: Iterable[int]) -> float:
+    return sum(arch.node_flops(cfg, d) for d, _g in request_nodes(cfg["tree"], tasks))

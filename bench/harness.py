@@ -17,6 +17,12 @@ sent is served to the end, and an answer may come up to a minute late, its
 latency counting the wait.  Tails are taken over every request sent in the
 window; one never answered counts as infinitely late.
 
+Each per-layer metric's reader gets the ``Window``: the requests, the
+program counters its reader file declares (``COUNTERS``, each read before
+and after the window), the configuration and its architecture module, and
+in a traced run the device trace's summary (``trace.py``, with every
+operation's device seconds) and the program's spans (``program_spans.py``).
+
 After the window the device's peak memory is read, the program is freed,
 and a sample of the served answers is compared with the plain reference
 (``compare.py``, ``reference.py``).
@@ -31,12 +37,13 @@ import shutil
 import sys
 import tempfile
 import time
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
-from bench import compare, flops, model
+from bench import compare, flops, model, program_spans
 from bench import trace as tracing
 from bench import traffic as traffic_mod
 from bench.reference import Reference
@@ -76,7 +83,9 @@ class Request:
 @dataclasses.dataclass
 class Window:
     """What a per-layer metric reader gets: the window's requests, the
-    program's counters over the window, and the trace when one was taken."""
+    program's counters over the window, the configuration and its
+    architecture module, and the device trace and program spans when a trace
+    was taken."""
 
     start: float
     end: float
@@ -85,7 +94,10 @@ class Window:
     compiles: int
     chips: int
     peak: Dict
+    cfg: Dict = dataclasses.field(default_factory=dict)
+    arch: Optional[ModuleType] = None
     trace: Optional[tracing.Summary] = None
+    spans: Optional[program_spans.Summary] = None
 
     @property
     def seconds(self) -> float:
@@ -162,14 +174,21 @@ def gc_pauses():
         gc.callbacks.remove(callback)
 
 
-def counters(session, engine) -> Dict[str, float]:
-    return {
-        "requests_admitted": session.requests_admitted,
-        "wait_sum": session.wait_sum,
-        "plan_seconds": session.plan_seconds,
-        "groups_executed": session.groups_executed,
-        "dispatches": engine.executor.dispatch_count,
-    }
+def counters(paths: Dict[str, str], session, engine) -> Dict[str, float]:
+    """Each counter's value, read along its dotted path from ``session`` or
+    ``engine``; a path the program lacks reads 0."""
+    roots = {"session": session, "engine": engine}
+    out = {}
+    for name, path in paths.items():
+        head, *attrs = path.split(".")
+        if head not in roots:
+            raise ValueError(f"counter {name!r}: {path!r} starts from neither "
+                             f"session nor engine")
+        value = roots[head]
+        for attr in attrs:
+            value = getattr(value, attr, None)
+        out[name] = float(value) if value is not None else 0.0
+    return out
 
 
 def serve_group(engine, tasks: Sequence[int], shape: int, prompts) -> None:
@@ -212,16 +231,16 @@ class Driver:
     """Sends the plan's requests into a session and watches their outputs."""
 
     def __init__(self, session, plan: traffic_mod.Plan, prompts, cfg: Dict,
-                 span: Callable[[str], Any]):
+                 arch: ModuleType, span: Callable[[str], Any]):
         self.session, self.plan, self.prompts, self.cfg = session, plan, prompts, cfg
-        self.span = span
+        self.arch, self.span = arch, span
         self.requests: List[Request] = []
         self.inflight: List[Request] = []
         self._flops: Dict[Tuple[int, ...], float] = {}
 
     def send(self, tasks: Tuple[int, ...], start: float, due: Optional[float] = None) -> None:
         if tasks not in self._flops:
-            self._flops[tasks] = flops.request_flops(self.cfg, tasks)
+            self._flops[tasks] = flops.request_flops(self.arch, self.cfg, tasks)
         r = Request(tasks=tasks, prompt=len(self.requests) % len(self.prompts),
                     start=start, due=due, flops=self._flops[tasks])
         r.sent = clock()
@@ -323,15 +342,17 @@ def run_cell(bench: Benchmark, workload: str, seed: int, seconds: float, trace: 
     devices = check_devices(cell["chips"]) if chip_check else jax.devices()
     peak = flops.peak(devices[0].device_kind)
     cfg = bench.config(cell["config"])
+    arch = bench.arch(cfg)
     traffic = bench.traffic(cell["traffic"])
     check = bench.check(workload)
+    counter_paths = bench.counters(workload)
     configure_cache()
 
     # ---------------------------------------------------------------- set-up
     num_tasks = len(cfg["num_classes"])
     plan = traffic_mod.make_plan(traffic, seed, seconds, cfg["seq_len"],
                                  cfg["vocab_size"], num_tasks)
-    program = model.build_program(cfg, seed)
+    program = model.build_program(cfg, seed, arch)
     engine = model.build_engine(program, cfg)
     prompts = jax.device_put([p[None, :] for p in plan.prompts])
     jax.block_until_ready((program.node_params, prompts))
@@ -350,28 +371,31 @@ def run_cell(bench: Benchmark, workload: str, seed: int, seconds: float, trace: 
         jax.profiler.start_trace(trace_dir)
 
     # ---------------------------------------------------------------- window
-    driver = Driver(session, plan, prompts, cfg, span)
-    before = counters(session, engine)
+    driver = Driver(session, plan, prompts, cfg, arch, span)
+    before = counters(counter_paths, session, engine)
     with compile_counter() as compiles, gc_pauses() as pauses, span("window"):
         t0 = clock()
         driver.run(t0, t0 + seconds, max_wait)
         close = clock()
-        after = counters(session, engine)
+        after = counters(counter_paths, session, engine)
         compiled = compiles["count"]
         paused = dict(pauses)
     driver.finish(close)
     print(f"window: {compiled} programs built; {paused['count']} garbage collections "
           f"paused {paused['seconds'] * 1e3:.1f} ms, the longest "
           f"{paused['longest'] * 1e3:.1f} ms", file=sys.stderr)
-    summary = None
+    summary = spans = None
     if trace:
         jax.profiler.stop_trace()
-        summary = tracing.reduce(tracing.load_events(tracing.find_xplane(trace_dir)))
+        events = tracing.load_events(tracing.find_xplane(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        summary, spans = tracing.reduce(events), program_spans.reduce(events)
+        del events
+        print(program_spans.table(spans), file=sys.stderr)
     window = Window(
         start=t0, end=close, requests=driver.requests,
         counters={k: after[k] - before[k] for k in after}, compiles=compiled,
-        chips=cell["chips"], peak=peak, trace=summary,
+        chips=cell["chips"], peak=peak, cfg=cfg, arch=arch, trace=summary, spans=spans,
     )
     memory_peak = max(
         (d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices[:cell["chips"]]
@@ -389,7 +413,7 @@ def run_cell(bench: Benchmark, workload: str, seed: int, seconds: float, trace: 
     del program, engine, session, driver, prompts
     gc.collect()
     t_ref = clock()
-    ref = Reference(cfg, seed)
+    ref = Reference(cfg, seed, arch)
     tasks = [r.tasks for r, _ in answers]
     expected = [ref.logits(plan.prompts[r.prompt], r.tasks) for r, _ in answers]
     numbers = compare.numbers([out for _, out in answers], expected, tasks,

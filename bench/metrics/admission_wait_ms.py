@@ -2,6 +2,9 @@
 scheduling policy admitted it, in milliseconds, from the session's
 ``wait_sum`` and ``requests_admitted`` counters over the window."""
 
+COUNTERS = {"requests_admitted": "session.requests_admitted",
+            "wait_sum": "session.wait_sum"}
+
 
 def read(window):
     admitted = window.counters["requests_admitted"]

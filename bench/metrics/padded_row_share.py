@@ -2,6 +2,9 @@
 padding, from the executor's ``rows_padded`` and ``rows_dispatched``
 counters (each counted once per task of a group)."""
 
+COUNTERS = {"rows_dispatched": "engine.executor.rows_dispatched",
+            "rows_padded": "engine.executor.rows_padded"}
+
 
 def read(window):
     rows = window.counters.get("rows_dispatched")

@@ -2,6 +2,9 @@
 (bucketing, padding, group ordering) per group executed, from the session's
 ``plan_seconds`` and ``groups_executed`` counters over the window."""
 
+COUNTERS = {"plan_seconds": "session.plan_seconds",
+            "groups_executed": "session.groups_executed"}
+
 
 def read(window):
     groups = window.counters["groups_executed"]

@@ -1,20 +1,21 @@
 """The system under test, built from a configuration file and a seed.
 
 The program is the repository's own: ``build_transformer_program`` over the
-configuration's task tree, served through ``MultitaskEngine`` under the
-configuration's policy.  Its weights are drawn by that same function, traced
-once under ``jax.jit`` so that they are made on the device in the type they
-are served in, in one call.
+configuration's task tree, with the configuration object that its
+architecture module's ``model_config`` makes, served through
+``MultitaskEngine`` under the configuration's policy.  Its weights are drawn
+by that same function, traced once under ``jax.jit`` so that they are made
+on the device in the type they are served in, in one call.
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, Tuple
 
 import jax
 
 from bench.seeds import weight_key
 from repro.core.task_graph import TaskGraph
-from repro.models.config import ModelConfig, make_config
 from repro.models.multitask import (
     build_transformer_program, program_trainable_params, program_with_params,
 )
@@ -22,33 +23,11 @@ from repro.serving import (
     AffinityPolicy, EnginePolicy, MultitaskEngine, RequestGroupScheduler,
 )
 
-ACTIVATIONS = {"silu": "swiglu"}
 
-
-def model_config(cfg: Dict) -> ModelConfig:
-    """The program's configuration object, from the published key names."""
-    if cfg["hidden_act"] not in ACTIVATIONS:
-        raise ValueError(f"unsupported hidden_act {cfg['hidden_act']!r}")
-    if cfg.get("sliding_window") is not None:
-        raise ValueError("sliding-window attention is not served by this path")
-    if sum(cfg["layers_per_depth"]) != cfg["num_hidden_layers"] or len(
-            cfg["layers_per_depth"]) != len(cfg["tree"]):
-        raise ValueError("layers_per_depth must split num_hidden_layers over the tree")
-    return make_config(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"], n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], activation=ACTIVATIONS[cfg["hidden_act"]],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=float(cfg["rms_norm_eps"]),
-        dtype=cfg["torch_dtype"], param_dtype=cfg["torch_dtype"],
-    )
-
-
-def build_program(cfg: Dict, seed: int):
+def build_program(cfg: Dict, seed: int, arch: ModuleType):
     """The task tree's program with its weights made on the device."""
     graph = TaskGraph.from_groups(cfg["tree"])
-    mcfg = model_config(cfg)
+    mcfg = arch.model_config(cfg)
     built = {}
 
     def init(key):

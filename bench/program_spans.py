@@ -1,12 +1,12 @@
-#!/usr/bin/env python3
 """The program's own spans in a traced window, and the idle time of the chip
 charged to the host work that was open while it idled.
 
 The program writes ``repro.*`` spans (``src/repro/core/spans.py``) into the
 same ``jax.profiler`` trace that ``trace.py`` reduces, so they share the
-device's clock.  Unlike the harness's ``bench.*`` spans they nest: a
-``repro.dispatch`` lies inside a ``repro.group`` inside a ``repro.pump``.
-For the window (``bench.window``) this module gives, per span name:
+device's clock; ``trace.load_events`` reads them with the rest.  Unlike the
+harness's ``bench.*`` spans they nest: a ``repro.dispatch`` lies inside a
+``repro.group`` inside a ``repro.pump``.  For the window (``bench.window``)
+this module gives, per span name:
 
 * ``count``: the spans that overlap the window;
 * ``total_s``: their seconds inside the window;
@@ -17,45 +17,31 @@ For the window (``bench.window``) this module gives, per span name:
 
 and the idle seconds with no program span open (the program was not running:
 the harness waited for an arrival, for admission's window or for outputs).
+A traced run hands the result to the metric readers as ``Window.spans`` and
+prints ``table`` on standard error.
 
-Run as a script it serves one cell's traced window through the harness, as
-``bench/run.py --trace 1`` does, and adds what the harness does not yet read
-(PERF.md, section 7): the window's program spans and the executor's row
-counters, the per-layer metrics read from them (``metrics/host_idle_share``,
-``dispatch_host_ms``, ``predict_ms_per_group``, ``padded_row_share``), and a
-table of idle seconds by innermost program span on standard error::
+Run as a script it is ``bench/run.py --trace 1`` with the same arguments::
 
   python3 bench/program_spans.py --workload <cell> --seed <n> --seconds <s>
 """
 from __future__ import annotations
 
-import time
-
-T_PROCESS = time.perf_counter()
-
-import os  # noqa: E402
-import sys  # noqa: E402
-
 if __name__ == "__main__":
-    ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
+    import os
+    import runpy
+    import sys
 
-import argparse  # noqa: E402
-import contextlib  # noqa: E402
-import dataclasses  # noqa: E402
-import json  # noqa: E402
-import math  # noqa: E402
-import traceback  # noqa: E402
-from typing import Dict, List, Optional, Sequence, Tuple  # noqa: E402
+    sys.argv += ["--trace", "1"]
+    runpy.run_path(os.path.join(os.path.dirname(os.path.abspath(__file__)), "run.py"),
+                   run_name="__main__")
 
-from bench import harness  # noqa: E402
-from bench import trace as tracing  # noqa: E402
+import dataclasses
+import math
+from typing import Dict, List, Sequence, Tuple
 
-PREFIX = "repro."
-# The per-layer metrics read from the program spans and row counters, with
-# their units.
-METRICS = {"host_idle_share": "%", "dispatch_host_ms": "ms",
-           "predict_ms_per_group": "ms", "padded_row_share": "%"}
+from bench import trace as tracing
+
+PREFIX = tracing.PROGRAM_SPAN_PREFIX
 
 
 @dataclasses.dataclass
@@ -78,24 +64,6 @@ class Summary:
     def host_idle_s(self) -> float:
         """Device idle time while a program span was open."""
         return sum(s.idle_s for s in self.spans.values())
-
-
-def load_events(xplane_path: str) -> List:
-    """The device operations, the harness's window and the program's spans of
-    a trace file."""
-    from jax.profiler import ProfileData
-
-    events = []
-    for plane in ProfileData.from_file(xplane_path).planes:
-        device = plane.name.startswith(tracing.DEVICE_PLANE_PREFIX)
-        for line in plane.lines:
-            if device and line.name != tracing.OPS_LINE:
-                continue
-            for e in line.events:
-                if device or e.name == tracing.WINDOW_SPAN or e.name.startswith(PREFIX):
-                    events.append(tracing.Event(plane.name, line.name, e.name,
-                                                float(e.start_ns), float(e.duration_ns)))
-    return events
 
 
 def innermost(spans: Sequence) -> List[Tuple[float, float, object]]:
@@ -190,83 +158,3 @@ def table(summary: Summary) -> str:
     lines += [f"  {name:<10} {s.idle_s:>10.6f} {s.self_s:>10.6f} {s.count:>8d}"
               for name, s in rows]
     return "\n".join(lines)
-
-
-@contextlib.contextmanager
-def recording():
-    """While open, a traced ``harness.run_cell`` also reduces the window's
-    program spans from its trace file (before the harness deletes it), adds
-    the executor's row counters to the window's counters, and hands both to
-    the metric readers on ``Window.spans`` and ``Window.counters``.  Yields a
-    dict that then holds the ``window``."""
-    seen: Dict = {}
-    find, counters, window = tracing.find_xplane, harness.counters, harness.Window
-
-    def find_xplane(trace_dir: str) -> str:
-        path = find(trace_dir)
-        seen["spans"] = reduce(load_events(path))
-        return path
-
-    def with_rows(session, engine) -> Dict[str, float]:
-        out = counters(session, engine)
-        out["rows_dispatched"] = getattr(engine.executor, "rows_dispatched", 0)
-        out["rows_padded"] = getattr(engine.executor, "rows_padded", 0)
-        return out
-
-    def with_spans(**fields):
-        w = window(**fields)
-        w.spans = seen.get("spans")
-        seen["window"] = w
-        return w
-
-    tracing.find_xplane, harness.counters, harness.Window = find_xplane, with_rows, with_spans
-    try:
-        yield seen
-    finally:
-        tracing.find_xplane, harness.counters, harness.Window = find, counters, window
-
-
-def run(bench, workload: str, seed: int, seconds: float, t_process: float,
-        chip_check: bool = True) -> Tuple[Dict, Optional[Summary]]:
-    """One traced run of a cell; its result line with the program-span
-    metrics added to ``metrics`` and the spans under ``program_spans``."""
-    with recording() as seen:
-        result, _ = harness.run_cell(bench, workload, seed, seconds, True, t_process,
-                                     chip_check=chip_check)
-    window, summary = seen["window"], seen.get("spans")
-    for name, unit in METRICS.items():
-        value = bench.reader(name)(window)
-        if value is not None:
-            result["metrics"][name] = {"value": value, "unit": unit}
-    if summary is not None:
-        result["program_spans"] = {
-            "idle_none_s": summary.unspanned_idle_s,
-            **{n: dataclasses.asdict(s) for n, s in summary.spans.items()},
-        }
-    result["rows"] = {k: window.counters[k] for k in ("rows_dispatched", "rows_padded")}
-    return result, summary
-
-
-def main(argv=None) -> int:
-    from bench.run import finite
-    from bench.spec import Benchmark
-
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    args = ap.parse_args(argv)
-    try:
-        result, summary = run(Benchmark(), args.workload, args.seed, args.seconds, T_PROCESS)
-    except Exception:  # any failure ends the run without a result line
-        traceback.print_exc()
-        print("bench: no result", file=sys.stderr)
-        return 1
-    if summary is not None:
-        print(table(summary), file=sys.stderr)
-    print(json.dumps(finite(result)), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

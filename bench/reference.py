@@ -5,13 +5,14 @@ draws its own weights from the seed, by the recipe the configuration states
 (the draws of ``build_transformer_program`` at the time the benchmark was
 defined: per tree node in canonical order, truncated-normal fan-in
 projections and a normal(0.02) embedding, cast to the parameter type), and
-computes each requested task on its own: the embedding, every decoder layer
-on the task's root-to-leaf path, then the task's head.  No activation cache,
+computes each requested task on its own: the embedding, every tree node on
+the task's root-to-leaf path, then the task's head.  No activation cache,
 no fused suffix, no batching: one prompt at a time, one tree node at a time.
 
-A decoder layer is Mistral's (RMSNorm, rotary attention with grouped KV
-heads, causal softmax, SwiGLU feed-forward), as in the published
-``config.json``.  A task head classifies the last position's hidden state,
+What a node's layers are is the architecture's: the module
+``archs/<model_type>.py`` that ``Benchmark.arch`` finds draws a node's
+weights (``init_node``) and applies them (``apply_node``), with the helpers
+below.  A task head classifies the last position's hidden state,
 standardised over the hidden axis, with a float32 linear probe.
 
 ``mode="f32"`` is the reference: every operation in float32 and every
@@ -24,7 +25,8 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, Sequence, Tuple
+from types import ModuleType
+from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,43 +41,23 @@ FP8_MAX = 448.0  # largest finite float8_e4m3fn
 
 # ----------------------------------------------------------------- weights
 
-def _dense(key, in_dim: int, out_shape: Tuple[int, ...], dtype) -> jax.Array:
+def dense(key, in_dim: int, out_shape: Tuple[int, ...], dtype) -> jax.Array:
+    """A fan-in projection: truncated normal within two deviations of
+    1/sqrt(in_dim), cast to ``dtype``."""
     std = 1.0 / math.sqrt(in_dim)
     return (std * jax.random.truncated_normal(
         key, -2.0, 2.0, (in_dim,) + tuple(out_shape))).astype(dtype)
 
 
-def _layer_weights(key, cfg: Dict) -> Dict:
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, hk, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    dt = jnp.dtype(cfg["torch_dtype"])
-    ka, km = jax.random.split(key)
-    kq, kk, kv, ko = jax.random.split(ka, 4)
-    k1, k2, k3 = jax.random.split(km, 3)
-    return {
-        "attn_norm": jnp.ones((d,), dt),
-        "mlp_norm": jnp.ones((d,), dt),
-        "wq": _dense(kq, d, (h, hd), dt),
-        "wk": _dense(kk, d, (hk, hd), dt),
-        "wv": _dense(kv, d, (hk, hd), dt),
-        "wo": _dense(ko, h * hd, (d,), dt),
-        "w_gate": _dense(k1, d, (f,), dt),
-        "w_up": _dense(k2, d, (f,), dt),
-        "w_down": _dense(k3, f, (d,), dt),
-    }
-
-
-def init_weights(cfg: Dict, key: jax.Array) -> Dict:
-    """Every node's stacked layers (and the embedding, at depth 0) and every
-    task head, drawn in the configuration's recipe."""
+def init_weights(cfg: Dict, arch: ModuleType, key: jax.Array) -> Dict:
+    """Every node's layers (and the embedding, at depth 0) and every task
+    head, drawn in the configuration's recipe."""
     dt = jnp.dtype(cfg["torch_dtype"])
     nodes = {}
     for d, groups in enumerate(canonical_tree(cfg["tree"])):
         for g in groups:
             key, sub = jax.random.split(key)
-            n = cfg["layers_per_depth"][d]
-            p = {"layers": jax.vmap(lambda k: _layer_weights(k, cfg))(
-                jax.random.split(sub, n))}
+            p = {"layers": arch.init_node(sub, cfg, d)}
             if d == 0:
                 p["embed"] = (jax.random.normal(
                     jax.random.fold_in(sub, 7),
@@ -84,7 +66,7 @@ def init_weights(cfg: Dict, key: jax.Array) -> Dict:
     heads = []
     for c in cfg["num_classes"]:
         key, sub = jax.random.split(key)
-        heads.append({"w": _dense(sub, cfg["hidden_size"], (c,), jnp.float32),
+        heads.append({"w": dense(sub, cfg["hidden_size"], (c,), jnp.float32),
                       "b": jnp.zeros((c,), jnp.float32)})
     return {"nodes": nodes, "heads": heads}
 
@@ -106,6 +88,8 @@ def _scaled_fp8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def product(spec: str, a: jax.Array, b: jax.Array, mode: str) -> jax.Array:
+    """A matrix product in float32 at ``Precision.HIGHEST``, with both
+    inputs rounded to scaled fp8 first where ``mode`` is ``"fp8"``."""
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     if mode == "f32":
         return jnp.einsum(spec, a, b, precision=HI)
@@ -115,51 +99,19 @@ def product(spec: str, a: jax.Array, b: jax.Array, mode: str) -> jax.Array:
     raise ValueError(f"unknown mode {mode!r}")
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def _rope(x, theta):
-    """Rotary embedding, rotating the two halves of each head (Mistral)."""
+def rope(x, theta):
+    """Rotary embedding of ``x`` (S, heads, dim), rotating the two halves of
+    each head (Mistral's layout)."""
     s, half = x.shape[0], x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _layer(x, w, cfg: Dict, mode: str):
-    """One Mistral decoder layer on one prompt ``x`` of shape (S, hidden)."""
-    f32 = lambda a: a.astype(jnp.float32)
-    h_, hk, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    eps, theta = float(cfg["rms_norm_eps"]), float(cfg["rope_theta"])
-    s = x.shape[0]
-    h = _rmsnorm(x, f32(w["attn_norm"]), eps)
-    q = _rope(product("sd,dhk->shk", h, w["wq"], mode), theta)
-    k = _rope(product("sd,dhk->shk", h, w["wk"], mode), theta)
-    v = product("sd,dhk->shk", h, w["wv"], mode)
-    k = jnp.repeat(k, h_ // hk, axis=1)  # query head i reads KV head i // (H/Hk)
-    v = jnp.repeat(v, h_ // hk, axis=1)
-    scores = product("shk,thk->hst", q, k, mode) / math.sqrt(hd)
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    att = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    o = product("hst,thk->shk", att, v, mode)
-    x = x + product("shk,hkd->sd", o, w["wo"].reshape(h_, hd, -1), mode)
-    h = _rmsnorm(x, f32(w["mlp_norm"]), eps)
-    a = jax.nn.silu(product("sd,df->sf", h, w["w_gate"], mode)) * product(
-        "sd,df->sf", h, w["w_up"], mode)
-    return x + product("sf,fd->sd", a, w["w_down"], mode)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_key", "mode"))
-def _node(layers, x, cfg_key, mode):
-    cfg = dict(cfg_key)
-
-    def body(h, w):
-        return _layer(h, w, cfg, mode), None
-
-    return jax.lax.scan(body, x, layers)[0]
 
 
 @jax.jit
@@ -174,18 +126,13 @@ def _head(head, x, mode):
     return product("d,dc->c", pooled, head["w"], mode) + head["b"]
 
 
-def _cfg_key(cfg: Dict) -> Tuple:
-    keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
-            "rms_norm_eps", "rope_theta")
-    return tuple((k, cfg[k]) for k in keys)
-
-
 class Reference:
-    """The configuration's weights for one seed, and its forward pass."""
+    """The configuration's weights for one seed, and its forward pass;
+    ``arch`` is the configuration's architecture module."""
 
-    def __init__(self, cfg: Dict, seed: int):
-        self.cfg = cfg
-        self.weights = jax.jit(functools.partial(init_weights, cfg))(weight_key(seed))
+    def __init__(self, cfg: Dict, seed: int, arch: ModuleType):
+        self.cfg, self.arch = cfg, arch
+        self.weights = jax.jit(functools.partial(init_weights, cfg, arch))(weight_key(seed))
 
     def logits(self, prompt: np.ndarray, tasks: Sequence[int],
                mode: str = "f32") -> Dict[int, np.ndarray]:
@@ -197,7 +144,8 @@ class Reference:
                 nodes = path(cfg["tree"], t)
                 x = _embed(w["nodes"][repr(nodes[0])]["embed"], jnp.asarray(prompt))
                 for node in nodes:
-                    x = _node(w["nodes"][repr(node)]["layers"], x, _cfg_key(cfg), mode)
+                    x = self.arch.apply_node(w["nodes"][repr(node)]["layers"], x, cfg,
+                                             node[0], mode)
                 out[t] = np.asarray(_head(w["heads"][t], x, mode), np.float32)
         return out
 

@@ -52,8 +52,9 @@ def main(argv=None) -> int:
     cfg, traffic = bench.config(cell["config"]), bench.traffic(cell["traffic"])
     if traffic["loop"] != "open":
         raise SystemExit("the sweep is for open-loop cells")
+    arch = bench.arch(cfg)
     harness.configure_cache()
-    program = model.build_program(cfg, args.seed)
+    program = model.build_program(cfg, args.seed, arch)
     engine = model.build_engine(program, cfg)
     plan0 = traffic_mod.make_plan(dict(traffic, rate_per_s=max(map(float, args.rates.split(",")))),
                                   args.seed, args.seconds, cfg["seq_len"], cfg["vocab_size"],
@@ -71,7 +72,7 @@ def main(argv=None) -> int:
                                          cfg["seq_len"], cfg["vocab_size"], len(cfg["num_classes"]))
             plan = dataclasses.replace(plan, prompts=plan0.prompts)
             session = engine.session(policy=policy, clock=harness.clock)
-            driver = harness.Driver(session, plan, prompts, cfg,
+            driver = harness.Driver(session, plan, prompts, cfg, arch,
                                     lambda name: contextlib.nullcontext())
             t0 = harness.clock()
             driver.run(t0, t0 + args.seconds, wait)

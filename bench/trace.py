@@ -8,11 +8,15 @@ a host span named ``bench.window``, and its own work in ``bench.*`` spans
 * busy time: the union of the intervals in which an XLA operation ran on a
   chip (line ``XLA Ops`` of each ``/device:TPU:<n>`` plane), clipped to the
   window and averaged over the chips that ran anything;
-* the operations that took most device time, named ``<program>:<op>`` by
-  the XLA program (line ``XLA Modules``) and the HLO instruction; an
-  operation nested inside another (the body of a loop) counts in its parent;
+* the device seconds of every operation, named ``<program>:<op>`` by the
+  XLA program (line ``XLA Modules``) and the HLO instruction, summed over
+  the chips; an operation nested inside another (the body of a loop) counts
+  in its parent.  The ten with most time are the breakdown's;
 * the idle gaps between operations, each named by the harness span that
   covers most of it (``none`` when no span does).
+
+The program's own spans (``repro.*``) are loaded from the same file and
+reduced by ``program_spans.py``.
 """
 from __future__ import annotations
 
@@ -24,6 +28,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
+PROGRAM_SPAN_PREFIX = "repro."
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -51,8 +56,8 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load_events(xplane_path: str) -> List[Event]:
-    """The device programs and operations and the harness's host spans of a
-    trace file."""
+    """The device programs and operations, the harness's host spans and the
+    program's spans of a trace file."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(xplane_path)
@@ -63,7 +68,7 @@ def load_events(xplane_path: str) -> List[Event]:
             if device and line.name not in (OPS_LINE, MODULES_LINE):
                 continue
             for e in line.events:
-                if device or e.name.startswith(SPAN_PREFIX):
+                if device or e.name.startswith((SPAN_PREFIX, PROGRAM_SPAN_PREFIX)):
                     events.append(Event(plane.name, line.name, e.name,
                                         float(e.start_ns), float(e.duration_ns)))
     return events
@@ -132,6 +137,7 @@ class Summary:
     chips: int
     device_ops: List[Tuple[str, float]]  # (XLA op name, seconds), longest first
     idle_gaps: List[Tuple[str, float]]   # (host span, seconds), longest first
+    op_seconds: Dict[str, float]         # every XLA op name: seconds
 
 
 def reduce(events: Sequence[Event], top: int = TOP) -> Summary:
@@ -175,4 +181,5 @@ def reduce(events: Sequence[Event], top: int = TOP) -> Summary:
         chips=chips,
         device_ops=[(name, ns * 1e-9) for name, ns in ops_sorted],
         idle_gaps=gaps[:top],
+        op_seconds={name: ns * 1e-9 for name, ns in op_time.items()},
     )

@@ -1,13 +1,15 @@
 """The benchmark harness end to end on the CPU, at a tiny width: the result
-line's shape, nothing compiled in the window, metrics found by name, the
-control and the faults that ``correct`` has to catch, and the refusal to run
-without a TPU."""
+line's shape, nothing compiled in the window, metrics found by name, what a
+reader is handed (declared counters, the configuration and its architecture,
+program spans, per-op device time), the control and the faults that
+``correct`` has to catch, and the refusal to run without a TPU."""
 import json
 import os
 import shutil
 import subprocess
 import sys
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +18,7 @@ import pytest
 from bench import harness, model
 from bench import traffic as traffic_mod
 from bench.spec import ROOT
-from benchtools import on_cpu, real_limits, tiny_tree
+from benchtools import on_cpu, probe_seen, real_limits, tiny_tree
 from repro.core.executor import TaskGraphExecutor
 
 SECONDS = 1.5
@@ -31,12 +33,13 @@ def run(bench, cell, trace=False, controls=()):
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    return tiny_tree(tmp_path_factory.mktemp("checkout"), extra_metric=True)
+    return tiny_tree(tmp_path_factory.mktemp("checkout"), extra_metric=True, probe=True)
 
 
 @pytest.fixture(scope="module")
 def open_traced(tiny):
-    return run(tiny, "tiny-t4.open", trace=True, controls=("fp8",))
+    result = run(tiny, "tiny-t4.open", trace=True, controls=("fp8",))
+    return result + (probe_seen(tiny),)
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +48,7 @@ def closed_run(tiny):
 
 
 def test_result_line_shape(open_traced, closed_run):
-    for result, _ in (open_traced, closed_run):
+    for result, *_ in (open_traced, closed_run):
         assert list(result)[:5] == ["correct", "attempted", "failed", "metrics", "device"]
         assert list(result)[-1] == "checks"
         assert result["correct"] is True
@@ -61,7 +64,7 @@ def test_end_to_end_metrics_of_an_untraced_run(closed_run):
 
 
 def test_per_layer_metrics_and_new_metric_file(open_traced):
-    result, _ = open_traced
+    result, *_ = open_traced
     metrics = result["metrics"]
     # Nothing compiles in the window: set-up warmed every subset x shape.
     assert metrics["compiles_in_window"]["value"] == 0
@@ -84,7 +87,7 @@ def test_warm_up_builds_every_program_the_traffic_can_reach(tiny):
     plan = traffic_mod.make_plan(traffic, SEED, SECONDS, cfg["seq_len"], cfg["vocab_size"],
                                  len(cfg["num_classes"]))
     prompts = jax.device_put([p[None, :] for p in plan.prompts])
-    engine = model.build_engine(model.build_program(cfg, SEED), cfg)
+    engine = model.build_engine(model.build_program(cfg, SEED, tiny.arch(cfg)), cfg)
     tally = harness.warm_up(engine, cfg, subsets, prompts)
     # Subsets whose programs others built are served at the first shape only.
     assert 0 < tally["groups"] < len(subsets) * len(shapes)
@@ -96,8 +99,39 @@ def test_warm_up_builds_every_program_the_traffic_can_reach(tiny):
     assert built["count"] == 0
 
 
+def test_a_reader_gets_the_window_difference_of_the_counters_it_declares(open_traced):
+    counters = open_traced[2]["counters"]
+    # The probe declares the executor's dispatch count under a name of its
+    # own, and a counter the program lacks.
+    assert counters["dispatches_seen"] > 0
+    assert counters["dispatches_seen"] == counters["dispatches"]
+    assert counters["absent"] == 0
+    # Other readers' declarations arrive with their names.
+    assert {"requests_admitted", "wait_sum", "plan_seconds", "groups_executed",
+            "rows_dispatched", "rows_padded"} <= set(counters)
+
+
+def test_a_traced_window_hands_readers_spans_config_arch_and_op_seconds(open_traced):
+    seen = open_traced[2]
+    assert seen["cfg"] == "tiny-t4"
+    assert seen["arch"] == "bench_arch_mistral" and seen["arch_model_config"]
+    assert seen["spans"]["dispatch"] >= seen["spans"]["group"] > 0
+    # The CPU has no TPU plane: every op's time is there, and there is none.
+    assert seen["op_seconds"] == {}
+
+
+def test_counters_are_read_along_their_paths():
+    session = types.SimpleNamespace(waits=2)
+    engine = types.SimpleNamespace(executor=types.SimpleNamespace(rows=5))
+    paths = {"w": "session.waits", "r": "engine.executor.rows",
+             "gone": "engine.executor.nothing.deeper"}
+    assert harness.counters(paths, session, engine) == {"w": 2.0, "r": 5.0, "gone": 0.0}
+    with pytest.raises(ValueError, match="neither session nor engine"):
+        harness.counters({"x": "executor.rows"}, session, engine)
+
+
 def test_control_fails_the_limit_that_the_program_meets(open_traced):
-    result, controls = open_traced
+    result, controls, _ = open_traced
     limit = real_limits()["logit_err"]
     assert result["correct"] is True
     assert result["checks"]["logit_err"]["value"] <= limit
@@ -111,8 +145,8 @@ def test_control_fails_the_limit_that_the_program_meets(open_traced):
 def _identity_block(monkeypatch):
     build = model.build_program
 
-    def broken(cfg, seed):
-        program = build(cfg, seed)
+    def broken(cfg, seed, arch):
+        program = build(cfg, seed, arch)
         program.block_fns[1] = lambda p, x: x  # a block that leaves its input as it was
         return program
 
@@ -134,8 +168,8 @@ def _half_batch(monkeypatch):
 def _altered_answer(monkeypatch):
     build = model.build_program
 
-    def broken(cfg, seed):
-        program = build(cfg, seed)
+    def broken(cfg, seed, arch):
+        program = build(cfg, seed, arch)
         head = program.head_fns[0]
         program.head_fns[0] = lambda p, x: head(p, x).at[..., 0].add(0.5)
         return program

@@ -36,6 +36,12 @@ def test_recorded_trace_top_ops_are_named_by_program(probe):
     assert sum(s for _, s in probe.device_ops) == pytest.approx(probe.busy_s, rel=1e-3)
 
 
+def test_recorded_trace_gives_every_op_its_device_seconds(probe):
+    assert sum(probe.op_seconds.values()) == pytest.approx(probe.busy_s, rel=1e-3)
+    top = sorted(probe.op_seconds.items(), key=lambda kv: -kv[1])[:trace.TOP]
+    assert probe.device_ops == top
+
+
 def test_recorded_trace_gaps_are_named_by_host_span(probe):
     label, seconds = probe.idle_gaps[0]
     assert label == "wait_arrival" and 0.004 < seconds < 0.005
@@ -60,6 +66,9 @@ def test_reduction_of_made_events():
     assert s.busy_s == pytest.approx(0.003)
     assert s.device_ops == [("jit_fused:%while.1", pytest.approx(0.002)),
                             ("jit_fused:%fusion.8", pytest.approx(0.001))]
+    assert s.op_seconds == dict(s.device_ops)
+    # Every op is in ``op_seconds``; the breakdown keeps the longest.
+    assert trace.reduce(events, top=1).op_seconds == s.op_seconds
     assert s.idle_gaps == [("wait_arrival", pytest.approx(0.004)),
                            ("wait_outputs", pytest.approx(0.002)),
                            ("step", pytest.approx(0.001))]

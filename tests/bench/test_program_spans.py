@@ -1,6 +1,6 @@
 """The reduction of the program's spans (``bench/program_spans.py``) on
-events made by hand, its readers, and a traced run of a tiny cell on the CPU
-with the program spans and row counters recorded."""
+events made by hand, its readers, and a traced run of a tiny cell through the
+harness on the CPU, whose readers get the program spans and row counters."""
 import time
 import types
 
@@ -9,13 +9,15 @@ import pytest
 from bench import harness, program_spans
 from bench import trace as tracing
 from bench.trace import Event
-from benchtools import on_cpu, tiny_tree
+from benchtools import on_cpu, probe_seen, tiny_tree
 
 MS = 1e6
 DEV = "/device:TPU:0"
 HOST = "/host:CPU"
 SEED = 2**33 + 777
 SECONDS = 1.5
+# The per-layer metrics read from the program spans and row counters.
+METRICS = ("host_idle_share", "dispatch_host_ms", "predict_ms_per_group", "padded_row_share")
 
 
 def span(name, start_ms, end_ms):
@@ -108,35 +110,39 @@ def test_readers_find_nothing_without_program_spans():
     # As the harness's Window has it without spans, and a program without
     # row counters.
     window = types.SimpleNamespace(counters={"dispatches": 3})
-    for name in program_spans.METRICS:
+    for name in METRICS:
         assert _reader(name)(window) is None
 
 
 @pytest.fixture(scope="module")
 def tiny_traced(tmp_path_factory):
-    tiny = tiny_tree(tmp_path_factory.mktemp("checkout"))
+    tiny = tiny_tree(tmp_path_factory.mktemp("checkout"), probe=True)
     with on_cpu():
-        return program_spans.run(tiny, "tiny-t4.open", SEED, SECONDS, time.perf_counter(),
-                                 chip_check=False)
+        result, _ = harness.run_cell(tiny, "tiny-t4.open", SEED, SECONDS, True,
+                                     time.perf_counter(), chip_check=False)
+    return result, probe_seen(tiny)
 
 
 def test_a_traced_cell_reports_the_program_span_metrics(tiny_traced):
-    result, summary = tiny_traced
+    result, seen = tiny_traced
     metrics = result["metrics"]
     assert result["correct"] is True
     assert metrics["dispatch_host_ms"]["value"] > 0
     assert metrics["predict_ms_per_group"]["value"] > 0
     assert 0 <= metrics["padded_row_share"]["value"] < 100
     # The CPU has no TPU plane: no idle time to charge.
-    assert "host_idle_share" not in metrics and summary.chips == 0
+    assert "host_idle_share" not in metrics
+    spans = seen["spans"]
     for name in ("pump", "admit", "plan", "order", "group", "dispatch", "predict",
                  "resolve"):
-        assert result["program_spans"][name]["count"] > 0
-    assert result["program_spans"]["dispatch"]["count"] >= result["program_spans"]["group"]["count"]
-    assert result["rows"]["rows_dispatched"] >= result["program_spans"]["dispatch"]["count"]
+        assert spans[name] > 0
+    assert spans["dispatch"] >= spans["group"]
+    assert seen["counters"]["rows_dispatched"] >= spans["dispatch"]
 
 
 def test_recording_leaves_the_harness_as_it_was(tiny_traced):
+    # The harness reads the program spans itself: nothing swaps its parts.
     assert harness.Window.__qualname__ == "Window"
     assert harness.counters.__qualname__ == "counters"
     assert tracing.find_xplane.__qualname__ == "find_xplane"
+    assert not hasattr(program_spans, "recording")
